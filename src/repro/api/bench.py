@@ -43,15 +43,13 @@ def bench_jax(tr: Dict, scale: float, workload: str,
     the shortest slice — on a CPU device vmap lanes are executed
     sequentially, so its wall cost scales with batch size.
     """
+    import jax
+
+    from repro.core import engine_jax
     from repro.core.presets import BASELINE
     from repro.sweep.grid import apply_point
 
-    try:
-        from repro.core import engine_jax
-    except Exception as e:  # pragma: no cover — jax missing/broken
-        print(f"  bench,name=sim_jax,skipped={type(e).__name__}")
-        return []
-
+    dev = jax.devices()[0]
     records: List[Dict] = []
 
     def lanes(b: int) -> List:
@@ -80,6 +78,8 @@ def bench_jax(tr: Dict, scale: float, workload: str,
         records.append({
             "name": f"sim_{label}",
             "engine": "jax",
+            "platform": dev.platform,
+            "device_kind": dev.device_kind,
             "native": False,
             "config": BASELINE.name,
             "workload": workload,

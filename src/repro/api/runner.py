@@ -145,20 +145,30 @@ def _run_cell_body(task: Tuple,
 
 
 def _pool_worker_main(task_q: Any, result_q: Any,
-                      worker_id: int) -> None:
+                      worker_id: int, coordinator: int) -> None:
     """Worker loop: execute tasks until a ``None`` sentinel.
 
     Top-level so it pickles under the spawn start method.  The worker
     never decides policy — every failure (including an injected chaos
     crash) is shipped to the coordinator as an ``("err", …)`` message
     with its full traceback; an injected OOM-kill simply dies here and
-    the coordinator reaps the process.
+    the coordinator reaps the process.  A worker whose coordinator
+    (pid ``coordinator``) died — ``kill -9``, possibly before this worker
+    finished starting — exits too, instead of idling as an orphan that
+    holds the coordinator's inherited output pipes open.
     """
     import signal as signal_mod
+    import threading
     try:                                 # the coordinator owns Ctrl-C
         signal_mod.signal(signal_mod.SIGINT, signal_mod.SIG_IGN)
     except ValueError:
         pass
+    def watch_parent() -> None:
+        while os.getppid() == coordinator:
+            time.sleep(0.5)
+        os._exit(0)
+
+    threading.Thread(target=watch_parent, daemon=True).start()
     while True:
         msg = task_q.get()
         if msg is None:
@@ -244,7 +254,8 @@ class Runner:
                  cell_timeout: Optional[float] = None,
                  backoff_s: float = 0.1, deadline_factor: float = 4.0,
                  chaos: Optional[FaultSpec] = None,
-                 preemptible: bool = True) -> None:
+                 preemptible: bool = True,
+                 batched_caps: Any = None) -> None:
         self.processes = processes
         self.progress = progress
         self.retries = retries
@@ -254,6 +265,10 @@ class Runner:
         #: explicit FaultSpec wins; else the REPRO_CHAOS env var applies
         self.chaos = chaos
         self.preemptible = preemptible
+        #: array extents (``engine_jax.Caps``) the batched backend lays
+        #: every trace out at; None = the envelope of each campaign's
+        #: own traces.  Campaigns run at equal caps share compiles.
+        self.batched_caps = batched_caps
         #: resilience counters of the most recent run_configs call
         self.last_stats: Dict[str, Any] = {}
 
@@ -387,7 +402,11 @@ class Runner:
                     self._execute_batched(to_run, common, retries, chaos,
                                           outcomes, on_ok, preempt,
                                           stats, journal_path, len(cells))
-                elif processes > 1 and len(to_run) > 1:
+                elif processes > 1 and len(to_run) > 1 \
+                        and engine != "jax":
+                    # the jax engine never fans out: every worker would
+                    # claim the one accelerator, so its cells run in
+                    # this process (the serial branch below)
                     self._execute_pool(to_run, common, processes,
                                        retries, cell_timeout, chaos,
                                        outcomes, on_ok, preempt, stats,
@@ -552,8 +571,9 @@ class Runner:
                          preempt: Optional[PreemptionHandler],
                          stats: Dict, journal_path: Optional[Path],
                          n_cells: int) -> None:
-        """One vmapped jax device program per (workload × shape
-        bucket) instead of one process per cell.
+        """One vmapped jax device program per shape bucket, scanning
+        every workload of the campaign side by side, instead of one
+        process per cell.
 
         The journal cell identity (``config_hash`` × workload) stays
         the unit of resume: every lane of a batch lands as its own
@@ -564,6 +584,11 @@ class Runner:
         """
         from repro.core import engine_jax
         scale, _engine, _native = common
+        # one layout for every trace of the campaign: each shape bucket
+        # compiles once, not once per workload
+        caps = self.batched_caps or engine_jax.Caps.cover(
+            [engine_jax.Caps.of(_get_trace(wl, scale))
+             for wl in sorted({cell["wl"] for cell in cells})])
         remaining: List[Dict] = [
             {"cell": cell, "attempt": 0} for cell in cells]
         while remaining:
@@ -589,43 +614,11 @@ class Runner:
                 except Exception as e:  # noqa: BLE001 — isolate the cell
                     errored.append((rec, f"{type(e).__name__}: {e}",
                                     traceback.format_exc()[-4000:]))
-            # one run_batch per workload; lanes grouped by shape bucket
-            by_wl: Dict[str, List[Tuple[Dict, Optional[str]]]] = {}
-            for item in runnable:
-                by_wl.setdefault(item[0]["cell"]["wl"], []).append(item)
-            for wl, group in by_wl.items():
-                self._check_preempt(preempt, outcomes, journal_path,
-                                    n_cells)
-                tr = _get_trace(wl, scale)
-                t0 = time.monotonic()
-                try:
-                    outs = engine_jax.run_batch(
-                        [rec["cell"]["sp"] for rec, _ in group], tr)
-                except Exception as e:  # noqa: BLE001 — retry the batch
-                    tb = traceback.format_exc()[-4000:]
-                    errored.extend((rec, f"{type(e).__name__}: {e}", tb)
-                                   for rec, _ in group)
-                    continue
-                wall = max(time.monotonic() - t0, 1e-9)
-                # aggregate throughput, attributed per lane
-                rate = len(tr["core"]) * len(group) / wall
-                for (rec, fault), (oi, od) in zip(group, outs):
-                    cell = rec["cell"]
-                    row = engine_jax.metrics_from_outputs(
-                        cell["sp"], tr, oi, od).row()
-                    if fault == "corrupt":
-                        row = chaos.corrupt_row(row)
-                    if _row_nonfinite(row):
-                        errored.append((rec, "corrupt row: non-finite "
-                                        "metrics", ""))
-                        continue
-                    outcomes[(cell["cfg_idx"], cell["wl"])] = {
-                        "status": "ok", "row": row, "rate": rate,
-                        "native": False, "attempts": rec["attempt"] + 1}
-                    on_ok(cell, row, rate, False, rec["attempt"] + 1)
-                if self.progress:
-                    print(f"[runner] batched {wl}: {len(group)} lanes "
-                          f"in {wall:.1f}s", file=sys.stderr)
+            # one run_batch for the round: lanes grouped by shape bucket,
+            # each lane scanning its own workload's trace
+            if runnable:
+                self._run_lanes(runnable, scale, caps, chaos, outcomes,
+                                on_ok, errored)
             remaining = []
             for rec, error, tb in errored:
                 rec["attempt"] += 1
@@ -636,6 +629,50 @@ class Runner:
                 else:
                     stats["retried"] += 1
                     remaining.append(rec)
+
+    def _run_lanes(self, group: List[Tuple[Dict, Optional[str]]],
+                   scale: float, caps: Any, chaos: Optional[FaultSpec],
+                   outcomes: Dict, on_ok: Callable,
+                   errored: List[Tuple[Dict, str, str]]) -> None:
+        """Run one batch of cells on the jax engine; a failed batch
+        lands every cell in ``errored`` for the retry round."""
+        from repro.core import engine_jax
+        trs = [_get_trace(rec["cell"]["wl"], scale) for rec, _ in group]
+        t0 = time.monotonic()
+        try:
+            outs = engine_jax.run_batch(
+                [rec["cell"]["sp"] for rec, _ in group], trs, caps=caps)
+        except engine_jax.JaxEngineError:
+            # an overflowed table or an input outside the engine's
+            # envelope is deterministic, not transient: retrying cannot
+            # help and a failure row would hide it
+            raise
+        except Exception as e:  # noqa: BLE001 — retry the batch
+            tb = traceback.format_exc()[-4000:]
+            errored.extend((rec, f"{type(e).__name__}: {e}", tb)
+                           for rec, _ in group)
+            return
+        wall = max(time.monotonic() - t0, 1e-9)
+        # aggregate throughput, attributed per lane
+        rate = sum(len(tr["core"]) for tr in trs) / wall
+        for (rec, fault), tr, (oi, od) in zip(group, trs, outs):
+            cell = rec["cell"]
+            row = engine_jax.metrics_from_outputs(
+                cell["sp"], tr, oi, od).row()
+            if fault == "corrupt":
+                row = chaos.corrupt_row(row)
+            if _row_nonfinite(row):
+                errored.append((rec, "corrupt row: non-finite metrics",
+                                ""))
+                continue
+            outcomes[(cell["cfg_idx"], cell["wl"])] = {
+                "status": "ok", "row": row, "rate": rate,
+                "native": False, "attempts": rec["attempt"] + 1}
+            on_ok(cell, row, rate, False, rec["attempt"] + 1)
+        if self.progress:
+            print(f"[runner] batched: {len(group)} lanes over "
+                  f"{len({id(t) for t in trs})} traces in {wall:.1f}s",
+                  file=sys.stderr)
 
     def _execute_pool(self, cells: List[Dict], common: Tuple,
                       processes: int, retries: int,
@@ -663,7 +700,8 @@ class Runner:
             next_wid += 1
             tq = ctx.SimpleQueue()
             proc = ctx.Process(target=_pool_worker_main,
-                               args=(tq, result_q, wid), daemon=True)
+                               args=(tq, result_q, wid, os.getpid()),
+                               daemon=True)
             proc.start()
             w = _Worker(wid, proc, tq)
             workers[wid] = w

@@ -54,8 +54,10 @@ def _add_sim_flags(ap: argparse.ArgumentParser,
     ap.add_argument("--backend", default="pool",
                     choices=["pool", "batched"],
                     help="execution backend: 'pool' fans cells out over "
-                         "worker processes; 'batched' runs whole config "
-                         "batches as one vmapped jax device program")
+                         "worker processes (the jax engine runs in this "
+                         "process instead: one process per chip); "
+                         "'batched' runs whole config batches as one "
+                         "vmapped jax device program")
     ap.add_argument("--processes", type=int, default=None,
                     help="worker processes (default: auto)")
     ap.add_argument("--no-native", action="store_true",
@@ -74,8 +76,11 @@ def _add_sim_flags(ap: argparse.ArgumentParser,
                          "applies regardless)")
     ap.add_argument("--resume", action="store_true",
                     help="resume an interrupted campaign from its "
-                         "journal (artifacts/<kind>/"
+                         "journal (<journal-dir>/"
                          "<spec_hash>.journal.jsonl)")
+    ap.add_argument("--journal-dir", default=None,
+                    help="directory of the campaign journal (default "
+                         "artifacts/<kind>/)")
     if preset_flag:
         ap.add_argument("--preset", default=None,
                         help="run one hierarchy preset instead of the "
@@ -122,6 +127,7 @@ def run_table(scale: float, engine: str = "soa", native: bool = True,
               retries: Optional[int] = None,
               cell_timeout: Optional[float] = None,
               resume: bool = False, backend: str = "pool",
+              journal_dir: Optional[str] = None,
               tool: str = "python -m repro table") -> Dict[str, Any]:
     """The `repro table` body — also the programmatic front door."""
     from repro.api.runner import Runner
@@ -142,7 +148,8 @@ def run_table(scale: float, engine: str = "soa", native: bool = True,
     runner = Runner(processes=processes, cell_timeout=cell_timeout,
                     **({} if retries is None else {"retries": retries}))
     art = runner.run(exp, kind="table", tool=tool,
-                     journal_dir=ARTIFACTS / "table", resume=resume)
+                     journal_dir=Path(journal_dir or ARTIFACTS / "table"),
+                     resume=resume)
     aggregates = art["result"]["aggregates"]
     _print_aggregate_table(aggregates)
 
@@ -161,15 +168,26 @@ def run_table(scale: float, engine: str = "soa", native: bool = True,
     return art
 
 
+def _degraded_rc(art: Dict[str, Any], cmd: str) -> int:
+    """Exit status of a campaign: 1 when any cell failed permanently."""
+    failures = art["provenance"].get("failures", [])
+    if failures:
+        print(f"[{cmd}] FAILED: degraded campaign, {len(failures)} "
+              f"cell(s) permanently failed", file=sys.stderr)
+        return 1
+    return 0
+
+
 def cmd_table(args: argparse.Namespace) -> int:
     from repro.api.registry import parse_set
-    run_table(_resolve_scale(args), engine=args.engine,
-              native=not args.no_native, processes=args.processes,
-              preset=args.preset, overrides=parse_set(args.sets) or None,
-              out=args.out, retries=args.retries,
-              cell_timeout=args.cell_timeout, resume=args.resume,
-              backend=args.backend)
-    return 0
+    art = run_table(_resolve_scale(args), engine=args.engine,
+                    native=not args.no_native, processes=args.processes,
+                    preset=args.preset,
+                    overrides=parse_set(args.sets) or None,
+                    out=args.out, retries=args.retries,
+                    cell_timeout=args.cell_timeout, resume=args.resume,
+                    backend=args.backend, journal_dir=args.journal_dir)
+    return _degraded_rc(art, "table")
 
 
 # ---------------------------------------------------------------------------
@@ -181,13 +199,16 @@ def run_sweep(scale: float, axes: Dict[str, list], tag: str,
               retries: Optional[int] = None,
               cell_timeout: Optional[float] = None,
               resume: bool = False, backend: str = "pool",
+              journal_dir: Optional[str] = None,
+              workloads: Optional[List[str]] = None,
+              runner: Any = None,
               tool: str = "python -m repro sweep") -> Dict[str, Any]:
     """Grid sweep of the four-row ladder; writes an ArtifactV1 whose
     ``result`` is the full sweep payload (points, Pareto front,
     recommended retune).
 
-    The campaign journals under ``artifacts/sweep/<spec_hash>
-    .journal.jsonl``; an interrupted run restarts with ``resume=True``
+    The campaign journals under ``<journal_dir>/<spec_hash>
+    .journal.jsonl`` (default ``artifacts/sweep/``); an interrupted run restarts with ``resume=True``
     and yields an artifact whose deterministic content (fingerprint) is
     bit-identical to an uninterrupted run.
     """
@@ -203,7 +224,9 @@ def run_sweep(scale: float, axes: Dict[str, list], tag: str,
     # engines are bit-identical by contract; CI asserts it)
     spec = {"name": tag, "grid": {k: list(v) for k, v in axes.items()},
             "scale": scale}
-    journal_path = (ARTIFACTS / "sweep"
+    if workloads is not None:
+        spec["workloads"] = list(workloads)
+    journal_path = (Path(journal_dir or ARTIFACTS / "sweep")
                     / f"{spec_hash(spec)[7:19]}.journal.jsonl")
     print(f"[sweep] {grid_size(axes)} points × 4-row ladder @ "
           f"scale={scale}, engine={engine}, backend={backend}")
@@ -212,7 +235,8 @@ def run_sweep(scale: float, axes: Dict[str, list], tag: str,
                                processes=processes, native=native,
                                retries=retries, cell_timeout=cell_timeout,
                                journal_path=journal_path, resume=resume,
-                               backend=backend)
+                               backend=backend, workloads=workloads,
+                               runner=runner)
     dt = time.time() - t0
     # failures and wall time are measurements of the run, not the
     # result — they live in provenance so resumed artifacts fingerprint
@@ -289,7 +313,10 @@ def cmd_sweep(args: argparse.Namespace) -> int:
                     native=not args.no_native, processes=args.processes,
                     out=args.out, retries=args.retries,
                     cell_timeout=args.cell_timeout, resume=args.resume,
-                    backend=args.backend)
+                    backend=args.backend, journal_dir=args.journal_dir)
+    rc = _degraded_rc(art, "sweep")
+    if rc:
+        return rc
     if args.smoke:
         # acceptance gate: every grid point evaluated, every ladder row
         # carries finite positive metrics (a NaN/garbage regression in
@@ -393,9 +420,12 @@ def cmd_bench(args: argparse.Namespace) -> int:
     # --smoke: the CI gate bundle — table + sweep + plan, one command.
     scale = args.scale if args.scale is not None else SMOKE_SCALE
     print(f"[bench] gate 1/3: table --smoke (scale={scale:g})")
-    run_table(scale, engine=args.engine, native=not args.no_native,
-              processes=args.processes,
-              tool="python -m repro bench --smoke")
+    art = run_table(scale, engine=args.engine, native=not args.no_native,
+                    processes=args.processes,
+                    tool="python -m repro bench --smoke")
+    rc = _degraded_rc(art, "bench")
+    if rc:
+        return rc
     print(f"\n== engine throughput (reference vs soa) ==")
     bench_engines(scale=scale, native=not args.no_native)
 
@@ -415,6 +445,9 @@ def cmd_bench(args: argparse.Namespace) -> int:
     if args.skip_plan:
         print("\n[bench] gate 3/3: plan --smoke SKIPPED (--skip-plan)")
         return 0
+    # the plan gate lowers on the 512-device CPU host platform, which
+    # must be set before jax starts — hence its own process, which pins
+    # itself to the CPU (launch/dryrun.py) and so never claims a chip
     print("\n[bench] gate 3/3: plan --smoke (subprocess: needs the "
           "512-device XLA host platform)")
     import subprocess

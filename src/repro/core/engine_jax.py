@@ -3,7 +3,7 @@
 A functional, array-state port of ``SoAHierarchySim``/``_sim_kernel.c``:
 all simulator state (tag stores, MESI directory, stride/ML prefetcher
 tables, tensor-aware reuse buckets, hybrid-memory heat counters) lives
-in fixed-shape int/float arrays threaded through one ``lax.scan`` over
+in fixed-shape integer arrays threaded through a ``lax.scan`` over
 the trace columns.  Numeric policy knobs are packed into a flat
 ``ConfigArrays`` pytree of scalars so ``jax.vmap`` evaluates N
 hierarchy points against one trace in a single jitted device program;
@@ -12,9 +12,11 @@ degree, replacement policy) are Python-static and select the compiled
 "shape bucket".
 
 Bit-identity with the reference engine is the contract
-(tests/test_simulator_equiv.py): every float op happens in the same
-order on IEEE doubles (x64 is enabled for the duration of a run), and
-every Python-dict tie-break is reproduced, using the same devices as
+(tests/test_simulator_equiv.py): every double operation of the C
+kernel happens in the same order and rounds the same way — on int64
+bit patterns in exact integer arithmetic (``core/f64bits.py``), since
+an accelerator's float64 need not be IEEE (x64 is enabled for the
+duration of a run) — and every Python-dict tie-break is reproduced, using the same devices as
 the C kernel (fill-sequence numbers, insertion-ordered linked dicts,
 first-index argmin/argmax).  Dict-shaped state maps onto arrays via:
 
@@ -42,36 +44,34 @@ diverging.
 from __future__ import annotations
 
 import dataclasses
-import os
-from functools import partial
+import hashlib
+import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
-
-# The legacy XLA:CPU runtime executes this scan ~2.5x faster than the
-# thunk runtime (measured on the tier-1 presets); prepend the flag
-# before the first jax import unless the user already chose a value.
-if "--xla_cpu_use_thunk_runtime" not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "") + " --xla_cpu_use_thunk_runtime=false"
-    ).strip()
-
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.core import f64bits as fb
 from repro.core import native as _native
 from repro.core import params as params_mod
 from repro.core.params import LINE_SIZE, PAGE_SIZE
+from repro.runtime import compile_cache
+
+compile_cache.configure()
 
 _EMPTY = np.int64(-(1 << 62))      # hash-slot "no key" sentinel
+_K1, _K2, _K3 = (fb.bits(v) for v in (1.0, 2.0, 3.0))  # bucket keys
 _PROBE = 32                        # linear-probe window (overflow-flagged)
 
 # overflow-flag bits (checked after the scan)
-_F_PAGE, _F_MK, _F_LD, _F_SHADOW, _F_BLK, _F_POOL = 1, 2, 4, 8, 16, 32
+_F_PAGE, _F_MK, _F_LD, _F_SHADOW, _F_BLK, _F_POOL, _F_RANGE = (
+    1, 2, 4, 8, 16, 32, 64)
 _FLAG_NAMES = {_F_PAGE: "page table", _F_MK: "markov table",
                _F_LD: "pending-dict hash", _F_SHADOW: "shadow hash",
-               _F_BLK: "block table probe", _F_POOL: "pending pool"}
+               _F_BLK: "block table probe", _F_POOL: "pending pool",
+               _F_RANGE: "utility counts beyond 2**40"}
 
 
 class JaxEngineError(RuntimeError):
@@ -191,6 +191,11 @@ def split_config(sp, nten: int) -> Tuple[StaticConfig, Dict[str, float]]:
         c2c_lat=float(cd[N.CD_C2C]), inv_lat=float(cd[N.CD_INV]),
         pf_throttle=float(cd[N.CD_PF_THROTTLE]),
     )
+    for c in (static.core_mlp, static.accel_mlp):
+        if not fb.exact_divisor(c):
+            raise JaxEngineUnsupported(
+                f"{sp.name}: MLP {c!r} is not a small odd multiple of a "
+                f"power of two (f64bits.div_const)")
     cfg = {
         "st_conf": int(ci[N.CI_ST_CONF]),
         "hp_hot": int(ci[N.CI_HP_HOT]),
@@ -271,11 +276,44 @@ def _lookup_slots(tab: np.ndarray, keys: np.ndarray) -> np.ndarray:
     return np.array([slot_of[k] for k in keys.tolist()], np.int64)
 
 
-class PreparedTrace:
-    """Trace columns + offline-derived slot columns and frozen tables."""
+@dataclasses.dataclass(frozen=True)
+class Caps:
+    """Array extents a trace is prepared at.  Every array shape of the
+    compiled scan derives from a StaticConfig, these extents and the lane
+    count, so traces prepared at equal caps share one compiled program
+    per shape bucket: a campaign prepares all its traces at their common
+    envelope (:meth:`cover`).  Larger extents never change a result."""
 
-    def __init__(self, static: StaticConfig, trace: Dict,
-                 pad_to: Optional[int] = None):
+    blk: int    # frozen block-table slots (MESI directory columns)
+    pg: int     # page-table slots (hybrid heat / persist / location)
+    n_pc: int   # dense per-PC prefetcher rows
+    mk: int     # markov-table slots per requester
+    nten: int   # tensor-aware rows
+
+    @staticmethod
+    def of(trace: Dict) -> "Caps":
+        """The extents this trace needs on its own."""
+        addr = np.asarray(trace["addr"], np.int64)
+        n = len(addr)
+        return Caps(
+            blk=_pow2_at_least(max(1024, 3 * len(np.unique(addr >> 6)))),
+            pg=_pow2_at_least(max(2048, 8 * len(np.unique(addr >> 12)))),
+            n_pc=len(np.unique(np.asarray(trace["pc"]))),
+            # markov capacity scales with trace length; overflow-flagged
+            mk=_pow2_at_least(min(max(4096, n // 4), 65536)),
+            nten=_nten(trace))
+
+    @staticmethod
+    def cover(caps: List["Caps"]) -> "Caps":
+        return Caps(*(max(getattr(c, f.name) for c in caps)
+                      for f in dataclasses.fields(Caps)))
+
+
+class PreparedTrace:
+    """Trace columns + offline-derived slot columns and frozen tables,
+    laid out at ``caps``."""
+
+    def __init__(self, static: StaticConfig, trace: Dict, caps: Caps):
         core = np.asarray(trace["core"], np.int64)
         pc = np.asarray(trace["pc"], np.int64)
         addr = np.asarray(trace["addr"], np.int64)
@@ -285,26 +323,27 @@ class PreparedTrace:
         n = len(core)
         if np.any(addr < 0):
             raise JaxEngineUnsupported("negative addresses unsupported")
+        if n >= 1 << 31:
+            # per-page heat counts accesses and is kept in int32
+            raise JaxEngineUnsupported(
+                f"{n} accesses: traces are bounded at 2**31 - 1")
 
         upc, pc_id = np.unique(pc, return_inverse=True)
-        self.n_pc = len(upc)
-        if static.pf_on and self.n_pc > min(static.st_tsize, 512):
+        if static.pf_on and len(upc) > min(static.st_tsize, 512):
             # dense per-PC prefetcher tables rely on the FIFO caps
             # (stride table / ML history dict) never firing
             raise JaxEngineUnsupported(
-                f"{self.n_pc} distinct PCs exceeds the dense prefetcher "
+                f"{len(upc)} distinct PCs exceeds the dense prefetcher "
                 f"table bound {min(static.st_tsize, 512)}")
+        self.n_pc = max(caps.n_pc, len(upc))
+        self.mk_cap = caps.mk
 
         blocks = addr >> 6
-        ublk = np.unique(blocks)
-        self.blk_tab = _build_table(
-            ublk, _pow2_at_least(max(1024, 3 * len(ublk))))
+        self.blk_tab = _build_table(np.unique(blocks), caps.blk)
         blk_slot = _lookup_slots(self.blk_tab, blocks)
 
         pages = addr >> 12
-        upage = np.unique(pages)
-        self.pg_cap = _pow2_at_least(max(2048, 8 * len(upage)))
-        self.pg_tab = _build_table(upage, self.pg_cap)
+        self.pg_tab = _build_table(np.unique(pages), caps.pg)
         self.pg_cap = len(self.pg_tab)
         pg_slot = _lookup_slots(self.pg_tab, pages)
         if static.hybrid and static.hbm_pages_max <= self.pg_cap:
@@ -319,40 +358,39 @@ class PreparedTrace:
         else:
             f1 = np.zeros(n, np.int64)
 
-        m = pad_to if pad_to and pad_to > n else n
         self.n = n
-        self.n_padded = m
-
-        def pad(a, fill=0):
-            if m == n:
-                return a
-            return np.concatenate(
-                [a, np.full(m - n, fill, a.dtype)])
-
         self.xs = {
-            "r": pad(core), "a": pad(addr), "w": pad(write, False),
-            "ten": pad(tensor), "reu": pad(reuse),
-            "pc": pad(pc_id.astype(np.int64)), "f1": pad(f1),
-            "blk_slot": pad(blk_slot), "pg_slot": pad(pg_slot),
-            "valid": pad(np.ones(n, bool), False),
+            "r": core, "a": addr, "w": write, "ten": tensor, "reu": reuse,
+            "pc": pc_id.astype(np.int64), "f1": f1,
+            "blk_slot": blk_slot, "pg_slot": pg_slot,
+            "valid": np.ones(n, bool),
         }
-        # markov capacity scales with trace length; overflow-flagged
-        env = os.environ.get("REPRO_JAX_MK_CAP")
-        self.mk_cap = (int(env) if env else
-                       _pow2_at_least(min(max(4096, n // 4), 65536)))
+
+    def padded(self, m: int) -> Dict[str, np.ndarray]:
+        """The step columns padded to ``m`` steps with no-op
+        (``valid=False``) steps."""
+        return {k: np.concatenate([v, np.zeros(m - self.n, v.dtype)])
+                for k, v in self.xs.items()}
 
 
 _PREP_CACHE: Dict[tuple, PreparedTrace] = {}
 
 
+def _trace_digest(trace: Dict) -> str:
+    h = hashlib.blake2b(digest_size=16)
+    for k in ("core", "pc", "addr", "write", "tensor", "reuse"):
+        h.update(np.ascontiguousarray(trace[k]).tobytes())
+    return h.hexdigest()
+
+
 def prepare_trace(static: StaticConfig, trace: Dict,
-                  pad_to: Optional[int] = None) -> PreparedTrace:
-    key = (trace.get("name"), len(trace["core"]), pad_to,
+                  caps: Caps) -> PreparedTrace:
+    key = (_trace_digest(trace), caps,
            static.pf_on, static.ml_on, static.ml_tsize, static.st_tsize,
            static.hybrid, static.hbm_pages_max)
     hit = _PREP_CACHE.get(key)
     if hit is None:
-        hit = PreparedTrace(static, trace, pad_to)
+        hit = PreparedTrace(static, trace, caps)
         if len(_PREP_CACHE) > 32:
             _PREP_CACHE.clear()
         _PREP_CACHE[key] = hit
@@ -375,8 +413,8 @@ def _cache_arrays(prefix: str, inst: int, S: int, A: int) -> Dict:
         prefix + "p": np.zeros(n, bool),
         prefix + "u": np.zeros(n, np.int64),
         prefix + "n": np.zeros(n, np.int64),
-        prefix + "l": np.zeros(n, np.float64),
-        prefix + "r": np.zeros(n, np.float64),
+        prefix + "l": np.zeros(n, np.int64),     # doubles as bit patterns
+        prefix + "r": np.zeros(n, np.int64),
         prefix + "q": np.zeros(n, np.int64),
         prefix + "_ctr": np.int64(0),
         prefix + "_ev": np.int64(0),
@@ -388,8 +426,11 @@ def _cache_arrays(prefix: str, inst: int, S: int, A: int) -> Dict:
 def _ta_arrays(prefix: str, inst: int, nten: int, shadow: int) -> Dict:
     shcap = _pow2_at_least(4 * shadow)
     return {
-        prefix + "_bkt": np.full((inst, nten), 3.0),
-        prefix + "_utl": np.full((inst, nten), 1.0),
+        # bucket as the order key of 1.0 / 2.0 / 3.0; utility as the
+        # exact quotient utn / utd (utd = 0: no fills, utility 1.0)
+        prefix + "_bkt": np.full((inst, nten), _K3, np.int64),
+        prefix + "_utn": np.zeros((inst, nten), np.int64),
+        prefix + "_utd": np.zeros((inst, nten), np.int64),
         prefix + "_fil": np.zeros((inst, nten), np.int64),
         prefix + "_hit": np.zeros((inst, nten), np.int64),
         prefix + "_ref": np.zeros((inst, nten), np.int64),
@@ -439,11 +480,12 @@ def init_state(S: StaticConfig, prep: PreparedTrace) -> Dict:
         st.update({"dinv": np.int64(0), "dc2c": np.int64(0),
                    "dupg": np.int64(0)})
     # memory channels
-    st.update({"db": np.float64(0), "ds": np.float64(0),
+    # every double of the state is held as its int64 bit pattern (f64bits)
+    st.update({"db": np.int64(0), "ds": np.int64(0),
                "dby": np.int64(0), "dac": np.int64(0),
                "drh": np.int64(0), "dop": np.full(8, -1, np.int64)})
     if S.hybrid:
-        st.update({"hb": np.float64(0), "hs": np.float64(0),
+        st.update({"hb": np.int64(0), "hs": np.int64(0),
                    "hby": np.int64(0), "hac": np.int64(0),
                    "hrh": np.int64(0), "hop": np.full(8, -1, np.int64),
                    "pgk": prep.pg_tab.copy(),
@@ -454,7 +496,7 @@ def init_state(S: StaticConfig, prep: PreparedTrace) -> Dict:
                    "epoch": np.int64(0), "sdec": np.int64(0),
                    "hpg": np.int64(0)})
     st.update({"mig": np.int64(0), "migb": np.int64(0),
-               "migs": np.float64(0)})
+               "migs": np.int64(0)})
     if S.pf_on:
         for k in ("sta", "sts", "stc", "sai", "sau"):
             st[k] = np.zeros((R, P), np.int64)
@@ -471,13 +513,14 @@ def init_state(S: StaticConfig, prep: PreparedTrace) -> Dict:
                        "mkc": np.zeros((R, mk), np.int64),
                        "mkd": np.zeros((R, mk, 9), np.int32),
                        "mko": np.zeros((R, mk, 9), np.int32)})
+            # perceptron weights in exact units of 1/8
             for k in ("wpc", "wd1", "wd2"):
-                st[k] = np.zeros((R, S.ml_tsize), np.float64)
-            st["wbs"] = np.zeros(R, np.float64)
+                st[k] = np.zeros((R, S.ml_tsize), np.int64)
+            st["wbs"] = np.zeros(R, np.int64)
             st.update(_ldict_arrays("mp", R, _MP_POOL, _MP_HASH, 3))
             st["mli"] = np.zeros(R, np.int64)
             st["mlt"] = np.zeros(R, np.int64)
-    st.update({"time": np.zeros(R, np.float64), "lat": np.float64(0),
+    st.update({"time": np.zeros(R, np.int64), "lat": np.int64(0),
                "nacc": np.int64(0), "wbl": np.int64(0),
                "pfd": np.int64(0), "flags": np.int64(0)})
     return st
@@ -496,11 +539,22 @@ def _h64j(x):
     return x
 
 
-# repro: lint-ok[TH002] known copy-insertion hazard, ROADMAP open item 1 — pre-update gathers on the dict carry cost ~13 µs/512 KB step on XLA:CPU; accepted until the fused-update rewrite lands
+# repro: lint-ok[TH002] known copy-insertion hazard, ROADMAP open item 1 — pre-update gathers on the dict carry; its cost on the chip is not measured yet, accepted until the fused-update rewrite lands
 def _make_step(S: StaticConfig):
     i64 = jnp.int64
-    f64 = jnp.float64
     R, NC = S.n_req, S.n_cores
+    # the static doubles of the timing model, computed on the host in
+    # the C kernel's operation order and carried as bit patterns
+    B = fb.bits
+    XFER = {ch: (B(64.0 / bw), B(64.0 / bw + gap))
+            for ch, bw, gap in (("d", S.d_bw, S.d_gap),
+                                ("h", S.h_bw, S.h_gap))}
+    LATC = {"d": (B(float(S.d_rhl)), B(float(S.d_bl))),
+            "h": (B(float(S.h_rhl)), B(float(S.h_bl)))}
+    PROMOTED = {"d": B(float(S.d_rhl) + 64.0 / S.d_bw),
+                "h": B(float(S.h_rhl) + 64.0 / S.h_bw)}
+    PAGE_XFER = {"d": B(4096.0 / S.d_bw), "h": B(4096.0 / S.h_bw)}
+    INF, ONE, TWO, TWELVE = B(float("inf")), B(1.0), B(2.0), B(12.0)
     S1, A1, s1b = S.s1, S.a1, S.s1b
     S2, A2, s2b = S.s2, S.a2, S.s2b
     S3, A3, s3b = S.s3, S.a3, S.s3b
@@ -508,16 +562,27 @@ def _make_step(S: StaticConfig):
            "l3": (A3, S3, s3b, S.ta3)}
     BIG_I = jnp.int64(1 << 62)
 
-    def pmod(v, m):  # Python (v * 2654435761) % m, m static > 0
-        return jnp.mod(v * jnp.int64(2654435761), m)
+    # 64-bit integer division, and shifts by traced amounts, are emulated
+    # on the TPU and dominate its compile time: divide by static powers
+    # of two with shifts and masks (exact for the floor semantics of
+    # Python and the C kernel), and keep traced shifts in int32
+    def div_c(v, d):  # v // d, d static > 0
+        return v >> (d.bit_length() - 1) if d & (d - 1) == 0 else v // d
 
-    def probe(keys, key):
-        """Windowed linear probe of a 1-D key table (``_EMPTY`` = free).
+    def mod_c(v, d):  # v % d (floor), d static > 0
+        return v & (d - 1) if d & (d - 1) == 0 else jnp.mod(v, d)
+
+    def pmod(v, m):  # Python (v * 2654435761) % m, m static > 0
+        return mod_c(v * jnp.int64(2654435761), m)
+
+    def probe(keys, key, row=None):
+        """Windowed linear probe of a 1-D key table (``_EMPTY`` = free),
+        or of row ``row`` of a 2-D one.
         Returns (slot, found, insert_slot_ok, window_exhausted)."""
-        cap = keys.shape[0]
+        cap = keys.shape[-1]
         home = (_h64j(key) & jnp.uint64(cap - 1)).astype(i64)
         idx = (home + jnp.arange(_PROBE, dtype=i64)) & (cap - 1)
-        ks = keys[idx]
+        ks = keys[idx] if row is None else keys[row, idx]
         match = ks == key
         empty = ks == _EMPTY
         stop = match | empty
@@ -574,6 +639,27 @@ def _make_step(S: StaticConfig):
 
         def flag(cond, bit):
             st["flags"] = st["flags"] | jnp.where(cond, i64(bit), i64(0))
+
+        def rolled(n, body):
+            """``for k in range(n): body(k)`` as one rolled loop, so the
+            body is traced and compiled once instead of n times.  The
+            carry is the part of the state ``body`` writes (found by a
+            trial trace); the rest stays read-only, so the compiler has
+            no reason to copy those buffers into the loop."""
+            outer = dict(st)
+            body(i64(0))
+            keys = [k for k in st if st[k] is not outer[k]]
+            st.clear()
+            st.update(outer)
+
+            def it(k, carry):
+                st.update(carry)
+                body(k)
+                return {key: st[key] for key in keys}
+            out = lax.fori_loop(0, n, it, {k: st[k] for k in keys})
+            st.clear()
+            st.update(outer)
+            st.update(out)
 
         # ---- linked dict (FIFO-capped map: C Fifo) ----------------------
         def ld_len(p, rr):
@@ -665,22 +751,29 @@ def _make_step(S: StaticConfig):
             return act, key, val
 
         # ---- tensor-aware shadow / bucket machinery ---------------------
+        def util_lt(n, d, t):
+            """The C kernel's ``util < t`` with util = min(n / d, 4.0),
+            or 1.0 without fills (d = 0), decided exactly (f64bits)."""
+            return jnp.where(d == 0, t["one"] == 1,
+                             fb.ratio_lt(n, jnp.maximum(d, 1), t)
+                             | (t["four"] == 1))
+
         def ta_bucket_upd(lv, inst, pred, t, all_rows):
             """Recompute utility+bucket; one tensor row (pred) or all
             rows (all_rows, used after a decay halving)."""
-            f_ = st[lv + "_fil"][inst].astype(f64)
-            h_ = st[lv + "_hit"][inst]
-            r_ = st[lv + "_ref"][inst]
-            num = (h_ + S.ta_sample * r_).astype(f64)
-            u_ = jnp.where(f_ == 0.0, 1.0,
-                           jnp.minimum(num / jnp.maximum(f_, 1.0), 4.0))
-            b_ = jnp.where(u_ < cfg["ta_low"], 1.0,
-                           jnp.where(u_ < cfg["ta_high"], 2.0, 3.0))
+            f_ = st[lv + "_fil"][inst]
+            num = st[lv + "_hit"][inst] + S.ta_sample * st[lv + "_ref"][inst]
+            flag(pred & jnp.any(num >= 1 << 40), _F_RANGE)
+            b_ = jnp.where(util_lt(num, f_, cfg["ta_low"]), _K1,
+                           jnp.where(util_lt(num, f_, cfg["ta_high"]),
+                                     _K2, _K3))
             rows = jnp.arange(S.nten)
             m = jnp.where(all_rows, jnp.ones(S.nten, bool), rows == t)
             m = m & pred
-            st[lv + "_utl"] = st[lv + "_utl"].at[inst].set(
-                jnp.where(m, u_, st[lv + "_utl"][inst]))
+            st[lv + "_utn"] = st[lv + "_utn"].at[inst].set(
+                jnp.where(m, num, st[lv + "_utn"][inst]))
+            st[lv + "_utd"] = st[lv + "_utd"].at[inst].set(
+                jnp.where(m, f_, st[lv + "_utd"][inst]))
             st[lv + "_bkt"] = st[lv + "_bkt"].at[inst].set(
                 jnp.where(m, b_, st[lv + "_bkt"][inst]))
 
@@ -708,14 +801,14 @@ def _make_step(S: StaticConfig):
             shk, _ = backshift(st[lv + "_shk"][inst], None, es, ev & ef)
             st[lv + "_shk"] = st[lv + "_shk"].at[inst].set(shk)
             st[lv + "_shh"] = st[lv + "_shh"].at[inst].set(
-                jnp.where(ev, jnp.mod(hd + 1, S.ta_shadow), hd))
+                jnp.where(ev, mod_c(hd + 1, S.ta_shadow), hd))
             st[lv + "_shl"] = st[lv + "_shl"].at[inst].add(
                 jnp.where(ev, -1, 0))
             # append at ring tail + hash insert (re-probe: backshift may
             # have moved the insertion hole)
             ln = st[lv + "_shl"][inst]
             hd2 = st[lv + "_shh"][inst]
-            pos = jnp.mod(hd2 + ln, S.ta_shadow)
+            pos = mod_c(hd2 + ln, S.ta_shadow)
             st[lv + "_shr"] = st[lv + "_shr"].at[inst, pos].set(
                 jnp.where(do_put, blk, st[lv + "_shr"][inst, pos]))
             s2_, f2_, ci2, ovf2 = probe(st[lv + "_shk"][inst], blk)
@@ -759,16 +852,16 @@ def _make_step(S: StaticConfig):
             last = st[lv + "l"][idx]
             seq = st[lv + "q"][idx]
             if ta_on:
-                inst = si // S_sets
+                inst = div_c(si, S_sets)
                 bkt = st[lv + "_bkt"][inst]
                 bvals = jnp.where(
                     st[lv + "p"][idx], cfg["ta_pref"],
                     jnp.where(st[lv + "u"][idx] == 0, cfg["ta_stream"],
                               bkt[st[lv + "n"][idx]]))
                 m1 = bvals == jnp.min(bvals)
-                lmask = jnp.where(m1, last, jnp.inf)
+                lmask = jnp.where(m1, last, INF)
             else:
-                inst = si // S_sets
+                inst = div_c(si, S_sets)
                 lmask = last
             m2 = lmask == jnp.min(lmask)
             sq = jnp.where(m2, seq, BIG_I)
@@ -797,42 +890,47 @@ def _make_step(S: StaticConfig):
 
         # ---- memory channels + hybrid page heat -------------------------
         def chan_access(ch, pred, now, addr, spec):
-            bl, rhl, bw, gap_c, rbb = (
-                (S.d_bl, S.d_rhl, S.d_bw, S.d_gap, S.d_rbb) if ch == "d"
-                else (S.h_bl, S.h_rhl, S.h_bw, S.h_gap, S.h_rbb))
+            rbb = S.d_rbb if ch == "d" else S.h_rbb
             st[ch + "ac"] = st[ch + "ac"] + jnp.where(pred, 1, 0)
             st[ch + "by"] = st[ch + "by"] + jnp.where(pred, 64, 0)
-            bank = jnp.mod(addr // rbb, 8)
-            row = addr // (rbb * 8)
+            bank = mod_c(div_c(addr, rbb), 8)
+            row = div_c(addr, rbb * 8)
             op = st[ch + "op"][bank]
             rowhit = op == row
             st[ch + "rh"] = st[ch + "rh"] + jnp.where(pred & rowhit, 1, 0)
             st[ch + "op"] = st[ch + "op"].at[bank].set(
                 jnp.where(pred & ~rowhit, row, op))
-            latc = jnp.where(rowhit, f64(rhl), f64(bl))
-            gap = jnp.where(rowhit, 0.0, gap_c)
-            xfer = 64.0 / bw + gap
+            latc = jnp.where(rowhit, *LATC[ch])
+            xfer = jnp.where(rowhit, *XFER[ch])
             busy = st[ch + "b"]
             sb_ = st[ch + "s"]
+            # times are non-negative: max/min on their bit patterns
             if spec:
                 start = jnp.maximum(jnp.maximum(now, busy), sb_)
-                st[ch + "s"] = jnp.where(pred, start + xfer, sb_)
+                st[ch + "s"] = jnp.where(pred, fb.add(start, xfer), sb_)
             else:
                 start = jnp.maximum(now, busy)
-                nb = start + xfer
+                nb = fb.add(start, xfer)
                 st[ch + "b"] = jnp.where(pred, nb, busy)
                 st[ch + "s"] = jnp.where(pred, jnp.maximum(sb_, nb), sb_)
-            done = start + latc + xfer
-            return done, done - now
+            done = fb.add(fb.add(start, latc), xfer)
+            return done, fb.sub(done, now)
 
         def decay_closed(h, p, k, half):
             """k lazy decay rounds in closed form: h halves each round;
             persist bumps while h (pre-halving) >= half, i.e. for
             bitlen(h // half) rounds; persist dies with the heat entry."""
-            kc = jnp.clip(k, 0, 63)
-            hf = h >> kc
-            hh = h // jnp.maximum(half, 1)
-            bl_ = 64 - lax.clz(hh)
+            # heat is bounded by the trace length (< 2**31, checked in
+            # PreparedTrace), so the bit arithmetic runs in int32:
+            # bitlen(h // half) = j + [h >= half << j], where j is the
+            # difference of the bit lengths of h and half (0 if j < 0)
+            i32 = jnp.int32
+            h32 = h.astype(i32)
+            half32 = jnp.maximum(half, 1).astype(i32)
+            hf = (h32 >> jnp.clip(k, 0, 31).astype(i32)).astype(i64)
+            j = lax.clz(half32) - lax.clz(h32)
+            ge = h32 >= (half32 << jnp.clip(j, 0, 31))
+            bl_ = jnp.where(j < 0, 0, j + ge.astype(i32)).astype(i64)
             bumps = jnp.minimum(k, bl_)
             pf = jnp.where(hf > 0, p + bumps, i64(0))
             return hf, pf
@@ -840,7 +938,7 @@ def _make_step(S: StaticConfig):
         def mem_access(pred, now, addr, spec, pg_slot):
             if not S.hybrid:
                 return chan_access("d", pred, now, addr, spec)
-            half = cfg["hp_hot"] // 2
+            half = cfg["hp_hot"] >> 1
             if pg_slot is None:
                 page = addr >> 12
                 slot, found, can_ins, ovf = probe(st["pgk"], page)
@@ -872,14 +970,13 @@ def _make_step(S: StaticConfig):
             st["hpg"] = st["hpg"] + jnp.where(promote, 1, 0)
             st["mig"] = st["mig"] + jnp.where(promote, 1, 0)
             st["migb"] = st["migb"] + jnp.where(promote, 4096, 0)
-            st["migs"] = jnp.where(promote, st["migs"] + cfg["migcost"],
-                                   st["migs"])
-            st["db"] = jnp.where(
-                promote, jnp.maximum(st["db"], now) + 4096.0 / S.d_bw,
-                st["db"])
-            st["hb"] = jnp.where(
-                promote, jnp.maximum(st["hb"], now) + 4096.0 / S.h_bw,
-                st["hb"])
+            st["migs"] = jnp.where(
+                promote, fb.add(st["migs"], cfg["migcost"]), st["migs"])
+            for ch in ("d", "h"):
+                st[ch + "b"] = jnp.where(
+                    promote,
+                    fb.add(jnp.maximum(st[ch + "b"], now), PAGE_XFER[ch]),
+                    st[ch + "b"])
             use_h = st["pgl"][slot] == 1
             dd, dv = chan_access("d", pred & ~use_h, now, addr, spec)
             hd_, hv_ = chan_access("h", pred & use_h, now, addr, spec)
@@ -890,17 +987,15 @@ def _make_step(S: StaticConfig):
             mem_access(pred, now, vaddr, True, None)
 
         def promote_wait(lv, pred, sl, pg_slot, now):
-            remaining = st[lv + "r"][sl] - now
+            remaining = fb.sub_clamp(st[lv + "r"][sl], now)
             if S.hybrid:
                 use_h = st["pgl"][pg_slot] == 1
-                rhl = jnp.where(use_h, f64(S.h_rhl), f64(S.d_rhl))
-                bw = jnp.where(use_h, f64(S.h_bw), f64(S.d_bw))
-                promoted = rhl + 64.0 / bw
+                promoted = jnp.where(use_h, PROMOTED["h"], PROMOTED["d"])
             else:
-                promoted = f64(S.d_rhl + 64.0 / S.d_bw)
+                promoted = i64(PROMOTED["d"])
             st[lv + "r"] = st[lv + "r"].at[sl].set(
-                jnp.where(pred, 0.0, st[lv + "r"][sl]))
-            return jnp.minimum(jnp.maximum(remaining, 0.0), promoted)
+                jnp.where(pred, 0, st[lv + "r"][sl]))
+            return jnp.minimum(remaining, promoted)
 
         # ---- MESI directory (dense columns over the frozen block table)
         def dir_evict_at(slot, pred, rr):
@@ -918,34 +1013,36 @@ def _make_step(S: StaticConfig):
                 return
             if S.ta3:
                 byp = ((reu == 0) & ~is_w
-                       & (st["l3_utl"][0, ten] < cfg["ta_bypass"]))
+                       & util_lt(st["l3_utn"][0, ten], st["l3_utd"][0, ten],
+                                 cfg["ta_bypass"]))
             else:
                 byp = jnp.bool_(False)
             ins = pred & ~byp
             s3 = blk & (S3 - 1)
             _, vd, va = c_insert("l3", ins, s3, s3, blk >> s3b, blk, ten,
                                  reu, now, jnp.bool_(False),
-                                 jnp.bool_(False), f64(0.0))
+                                 jnp.bool_(False), i64(0))
             wb(vd, now, va)
 
         def fill_private(pred, rr, blk, ten, reu, now, is_w):
             s2 = blk & (S2 - 1)
             v2, vd2, va2 = c_insert("l2", pred, rr * S2 + s2, s2,
                                     blk >> s2b, blk, ten, reu, now, is_w,
-                                    jnp.bool_(False), f64(0.0))
+                                    jnp.bool_(False), i64(0))
             if S.mesi:
                 # victim leaves the private hierarchy entirely only when
                 # it is not also resident in this requester's L1
                 vblk = va2 >> 6
                 s1v = vblk & (S1 - 1)
                 in_l1, _, _ = c_probe("l1", rr * S1 + s1v, vblk >> s1b)
-                dslot, dfound, _, _ = probe(consts["blk"], vblk)
+                dslot, dfound, _, _ = probe(consts["blk"], vblk,
+                                            consts["tid"])
                 dir_evict_at(dslot, v2 & ~in_l1 & dfound, rr)
             wb(vd2, now, va2)
             s1 = blk & (S1 - 1)
             _, vd1, va1 = c_insert("l1", pred, rr * S1 + s1, s1,
                                    blk >> s1b, blk, ten, reu, now, is_w,
-                                   jnp.bool_(False), f64(0.0))
+                                   jnp.bool_(False), i64(0))
             vblk1 = va1 >> 6
             s2v = vblk1 & (S2 - 1)
             hit2, w2, _ = c_probe("l2", rr * S2 + s2v, vblk1 >> s2b)
@@ -972,7 +1069,7 @@ def _make_step(S: StaticConfig):
                     _, vd, va = c_insert(
                         "l2", cp, rr * S2 + s2, s2, blk >> s2b, blk, ten,
                         reu, now, jnp.bool_(False), jnp.bool_(True),
-                        now + cfg["hl3"])
+                        fb.add(now, cfg["hl3"]))
                     wb(vd, now, va)
                 act = act & ~in3
             # throttle on the target channel's speculative backlog
@@ -983,12 +1080,13 @@ def _make_step(S: StaticConfig):
                 st["pgk"] = st["pgk"].at[pslot].set(
                     jnp.where(act & ~pfound, page, st["pgk"][pslot]))
                 use_h = st["pgl"][pslot] == 1
-                backlog = jnp.where(use_h, st["hs"] - st["hb"],
-                                    st["ds"] - st["db"])
+                sb_ = jnp.where(use_h, st["hs"], st["ds"])
+                bb_ = jnp.where(use_h, st["hb"], st["db"])
             else:
                 pslot = None
-                backlog = st["ds"] - st["db"]
-            drop = act & (backlog > S.pf_throttle)
+                sb_, bb_ = st["ds"], st["db"]
+            # spec_busy - busy > throttle (a negative backlog never is)
+            drop = act & (fb.sub_clamp(sb_, bb_) > B(S.pf_throttle))
             st["pfd"] = st["pfd"] + jnp.where(drop, 1, 0)
             act = act & ~drop
             done, _ = mem_access(act, now, tgt, True, pslot)
@@ -1031,28 +1129,30 @@ def _make_step(S: StaticConfig):
             issue = upd & (ncf >= cfg["st_conf"]) & (nst != 0)
             iss = st["sai"][rr, pc]
             used = st["sau"][rr, pc]
-            ratio = used.astype(f64) / jnp.maximum(iss, 1).astype(f64)
-            issue = issue & ~((iss >= 32) & (ratio < 0.4))
-            tgts = []
-            for k in range(1, S.st_deg + 1):
-                tgt = a + nst * k
-                tgts.append(tgt)
+            # the C kernel's used / iss < 0.4 in doubles, exactly: with
+            # iss < 2**53 the quotient rounds below 0.4 iff it is below 2/5
+            issue = issue & ~((iss >= 32) & (5 * used < 2 * iss))
+            def put_one(k):
                 st["sai"] = st["sai"].at[rr, pc].add(
                     jnp.where(issue, 1, 0))
                 ev = issue & (ld_len("sp", rr) > 4096)
                 ld_evict("sp", rr, ev)
-                ld_put("sp", rr, tgt >> 6, [pc], issue)
+                ld_put("sp", rr, (a + nst * (k + 1)) >> 6, [pc], issue)
+            rolled(S.st_deg, put_one)
             st["sti"] = st["sti"].at[rr].add(
                 jnp.where(issue, S.st_deg, 0))
-            return issue, tgts
+            # the k-th candidate (k = 1..st_deg) targets a + nst * k
+            return issue, nst
 
         def ml_train(pred, rr, ff1, ff2, ff3, useful):
-            lr = 0.5 if useful else -0.5
+            # weights in units of 1/8: every value the C kernel's doubles
+            # take (steps of 0.5 and 0.125, clipped to +-8) is exact
+            lr = 4 if useful else -4
             for w, f in (("wpc", ff1), ("wd1", ff2), ("wd2", ff3)):
-                v = jnp.clip(st[w][rr, f] + lr, -8.0, 8.0)
+                v = jnp.clip(st[w][rr, f] + lr, -64, 64)
                 st[w] = st[w].at[rr, f].set(
                     jnp.where(pred, v, st[w][rr, f]))
-            vb = jnp.clip(st["wbs"][rr] + lr * 0.25, -8.0, 8.0)
+            vb = jnp.clip(st["wbs"][rr] + lr // 4, -64, 64)
             st["wbs"] = st["wbs"].at[rr].set(
                 jnp.where(pred, vb, st["wbs"][rr]))
             st["mlt"] = st["mlt"].at[rr].add(jnp.where(pred, 1, 0))
@@ -1140,6 +1240,7 @@ def _make_step(S: StaticConfig):
             f3 = pmod(d_new, S.ml_tsize)
             score = (st["wpc"][rr, ff1] + st["wd1"][rr, f2]
                      + st["wd2"][rr, f3] + st["wbs"][rr])
+            # cfg["ml_thresh"] is ceil(8 * threshold): score/8 >= t exactly
             emit = bb & (score >= cfg["ml_thresh"])
             st["mli"] = st["mli"].at[rr].add(jnp.where(emit, 1, 0))
             ev = bb & (ld_len("mp", rr) > 2048)
@@ -1177,7 +1278,7 @@ def _make_step(S: StaticConfig):
         t1 = blk >> s1b
         s1 = blk & (S1 - 1)
         si1 = rr * S1 + s1
-        lat = cfg["hl1"] + jnp.float64(0.0)
+        lat = cfg["hl1"]
 
         # ---- L1 ----
         hit1, w1, _ = c_probe("l1", si1, t1)
@@ -1195,18 +1296,18 @@ def _make_step(S: StaticConfig):
         st["l1d"] = st["l1d"].at[sl1].set(
             jnp.where(h1p & w, True, st["l1d"][sl1]))
         pw1 = h1p & (st["l1r"][sl1] > now)
-        lat = jnp.where(pw1, lat + promote_wait("l1", pw1, sl1,
-                                                x["pg_slot"], now), lat)
+        lat = jnp.where(pw1, fb.add(lat, promote_wait(
+            "l1", pw1, sl1, x["pg_slot"], now)), lat)
         miss1 = act0 & ~hit1
         st["l1m"] = st["l1m"].at[rr].add(jnp.where(miss1, 1, 0))
 
         # ---- prefetcher observation (on L1 miss) ----
         if S.pf_on:
-            issue, tgts = stride_observe(miss1, rr, x["pc"], a)
+            issue, nst = stride_observe(miss1, rr, x["pc"], a)
             if S.ml_on:
                 emit_ml, tgt_ml = ml_observe(miss1, rr, x["pc"],
                                              x["f1"], a)
-        lat = jnp.where(miss1, lat + cfg["hl2"], lat)
+        lat = jnp.where(miss1, fb.add(lat, cfg["hl2"]), lat)
 
         # ---- L2 ----
         s2 = blk & (S2 - 1)
@@ -1227,19 +1328,18 @@ def _make_step(S: StaticConfig):
         st["l2d"] = st["l2d"].at[sl2].set(
             jnp.where(h2p & w, True, st["l2d"][sl2]))
         pw2 = h2p & (st["l2r"][sl2] > now)
-        lat = jnp.where(pw2, lat + promote_wait("l2", pw2, sl2,
-                                                x["pg_slot"], now), lat)
+        lat = jnp.where(pw2, fb.add(lat, promote_wait(
+            "l2", pw2, sl2, x["pg_slot"], now)), lat)
         # L2 hit copies into L1 (victim writeback dropped, C semantics)
         c_insert("l1", h2p, si1, s1, t1, blk, ten, reu, now, w,
-                 jnp.bool_(False), f64(0.0))
+                 jnp.bool_(False), i64(0))
         miss2 = miss1 & ~hit2
         st["l2m"] = st["l2m"].at[rr].add(jnp.where(miss2, 1, 0))
 
         # ---- prefetch issue (on L2 miss) ----
         if S.pf_on:
-            for k in range(S.st_deg):
-                do_prefetch(miss2 & issue, rr, tgts[k], ten, reu, now,
-                            True)
+            rolled(S.st_deg, lambda k: do_prefetch(
+                miss2 & issue, rr, a + nst * (k + 1), ten, reu, now, True))
             if S.ml_on:
                 do_prefetch(miss2 & emit_ml, rr, tgt_ml, ten, reu, now,
                             False)
@@ -1282,15 +1382,15 @@ def _make_step(S: StaticConfig):
                    & iact[:, None])
             st["l2v"] = st["l2v"].at[idx2v].set(
                 jnp.where(m2v, False, st["l2v"][idx2v]))
-            lat = jnp.where(inv_act, lat + S.inv_lat, lat)
+            lat = jnp.where(inv_act, fb.add(lat, B(S.inv_lat)), lat)
             served = prov
 
         cont3 = miss2 & ~served
         l3hit = jnp.bool_(False)
         if S.has_l3:
             if S.mesi:
-                lat = jnp.where(served, lat + S.c2c_lat, lat)
-            lat = jnp.where(cont3, lat + cfg["hl3"], lat)
+                lat = jnp.where(served, fb.add(lat, B(S.c2c_lat)), lat)
+            lat = jnp.where(cont3, fb.add(lat, cfg["hl3"]), lat)
             s3 = blk & (S3 - 1)
             hit3, w3, _ = c_probe("l3", s3, blk >> s3b)
             h3p = cont3 & hit3
@@ -1313,8 +1413,8 @@ def _make_step(S: StaticConfig):
 
         # ---- demand memory access (merged: miss path + c2c w/o L3) ----
         dem = bm if S.has_l3 else (bm | served)
-        _, svc = mem_access(dem, now + lat, a, False, x["pg_slot"])
-        lat = jnp.where(dem, lat + svc, lat)
+        _, svc = mem_access(dem, fb.add(now, lat), a, False, x["pg_slot"])
+        lat = jnp.where(dem, fb.add(lat, svc), lat)
         fs_pred = (bm | served) if S.has_l3 else bm
         fill_shared(fs_pred, blk, ten, reu, now, bm & w)
         fill_private(bm | served | l3hit, rr, blk, ten, reu, now, w)
@@ -1322,13 +1422,13 @@ def _make_step(S: StaticConfig):
         # ---- retire ----
         hitdone = h1p | h2p | served | l3hit
         active = hitdone | bm
-        st["lat"] = jnp.where(active, st["lat"] + lat, st["lat"])
+        st["lat"] = jnp.where(active, fb.add(st["lat"], lat), st["lat"])
         st["nacc"] = st["nacc"] + jnp.where(active, 1, 0)
-        mlp = jnp.where(rr >= NC, f64(S.accel_mlp), f64(S.core_mlp))
-        d_ = lat / mlp
-        slow = now + jnp.maximum(d_, 2.0)
-        fast = hitdone & (lat <= cfg["hl1"] + 12.0)
-        newt = jnp.where(fast, now + 1.0, slow)
+        d_ = jnp.where(rr >= NC, fb.div_const(lat, S.accel_mlp),
+                       fb.div_const(lat, S.core_mlp))
+        slow = fb.add(now, jnp.maximum(d_, TWO))
+        fast = hitdone & (lat <= fb.add(cfg["hl1"], TWELVE))
+        newt = jnp.where(fast, fb.add(now, ONE), slow)
         st["time"] = st["time"].at[rr].set(
             jnp.where(active, newt, st["time"][rr]))
         return st
@@ -1374,59 +1474,59 @@ def _export_arrays(S: StaticConfig, st: Dict):
         if S.ml_on:
             oi = oi.at[82:82 + R].set(st["mli"])
             oi = oi.at[90:90 + R].set(st["mlt"])
-    od = jnp.zeros(10, jnp.float64)
+    od = jnp.zeros(10, jnp.int64)          # bit patterns of the doubles
     od = od.at[0:R].set(st["time"])
     od = od.at[8].set(st["lat"]).at[9].set(st["migs"])
     return oi, od, st["flags"]
 
 
+#: scan steps per device call.  A longer trace runs as a sequence of
+#: calls that carry the state on the device, so the compiled program is
+#: independent of the trace length.
+CHUNK = 1 << 16
+
+
 def _make_run(static: StaticConfig, batched: bool):
+    """``(scan, export)``: ``scan`` advances a state over one chunk of
+    trace steps, ``export`` reduces a final state to the C kernel's
+    counter vectors.  Batched, every lane carries its own state.
+
+    The chunk holds the steps of T traces (``xs`` leaves ``[C, T]``, the
+    frozen block tables ``[T, blk]``); lane ``tid`` runs trace ``tid``,
+    so one program scans every trace of a campaign side by side."""
     step = _make_step(static)
 
-    def run_one(consts, cfg, st0, xs):
+    def scan(consts, cfg, st, xs, tid):
+        lane_consts = {"blk": consts["blk"], "tid": tid}
+
         def body(s, x):
-            return step(consts, cfg, s, x), None
-        stf, _ = lax.scan(body, st0, xs)
-        return _export_arrays(static, stf)
+            return step(lane_consts, cfg, s,
+                        {k: v[tid] for k, v in x.items()}), None
+        return lax.scan(body, st, xs)[0]
 
-    f = run_one
+    def export(st):
+        return _export_arrays(static, st)
+
     if batched:
-        # cfg rows vary per lane; consts / initial state / trace are
-        # shared and broadcast by the vmap batching rule
-        f = jax.vmap(run_one, in_axes=(None, 0, None, None))
-    return jax.jit(f)
+        # cfg rows, states and trace indices vary per lane; the block
+        # tables and the trace chunk are shared by all lanes
+        scan = jax.vmap(scan, in_axes=(None, 0, 0, None, 0))
+        export = jax.vmap(export)
+    return jax.jit(scan, donate_argnums=2), jax.jit(export)
 
 
-_RUN_CACHE: Dict[tuple, object] = {}
+#: compiled ``(scan, export)`` by (StaticConfig, batched, argument shapes)
+_COMPILED: Dict[tuple, tuple] = {}
 
-
-def _get_run(static: StaticConfig, batched: bool):
-    key = (static, batched)
-    fn = _RUN_CACHE.get(key)
-    if fn is None:
-        fn = _make_run(static, batched)
-        _RUN_CACHE[key] = fn
-    return fn
-
-
-_CACHE_INIT = False
-
-
-def _maybe_persistent_cache() -> None:
-    global _CACHE_INIT
-    if _CACHE_INIT:
-        return
-    _CACHE_INIT = True
-    d = os.environ.get("REPRO_JAX_CACHE_DIR")
-    if d:
-        try:
-            jax.config.update("jax_compilation_cache_dir", d)
-        except Exception:
-            pass
+#: one record per executed shape bucket, oldest first: the bucket's first
+#: config name, its lanes, its distinct traces, the scan steps, the
+#: simulated accesses (summed over lanes), and the seconds spent
+#: compiling and scanning (device work ended by ``block_until_ready``)
+SCAN_LOG: List[Dict] = []
 
 
 def _x64():
-    return jax.experimental.enable_x64()
+    return jax.enable_x64(True)
 
 
 def _nten(trace: Dict) -> int:
@@ -1434,13 +1534,37 @@ def _nten(trace: Dict) -> int:
     return int(tensor.max()) + 1 if len(tensor) else 1
 
 
+def _lower_cfg(arrays: Dict) -> Dict:
+    """The device form of the lane knobs (scalars or lane-stacked).  No
+    double goes to the device as a double (the chip rounds them, see
+    f64bits): latencies and costs become bit patterns, rank knobs order
+    keys, utility thresholds the constants of ``f64bits.ratio_lt``, and
+    the perceptron threshold ceil(8 t) for scores in units of 1/8."""
+    out = {k: np.asarray(arrays[k], np.int64) for k in _CFG_I}
+    f = {k: np.asarray(arrays[k], np.float64) for k in _CFG_F}
+    for k in ("hl1", "hl2", "hl3", "migcost"):
+        out[k] = f[k].view(np.int64)
+    for k in ("ta_pref", "ta_stream"):
+        b = f[k].view(np.int64)
+        out[k] = np.where(b < 0, b ^ np.int64((1 << 63) - 1), b)
+    for k in ("ta_low", "ta_high", "ta_bypass"):
+        try:
+            rows = [fb.ratio_threshold(t) for t in f[k].ravel().tolist()]
+        except ValueError as e:
+            raise JaxEngineUnsupported(f"{k}: {e}") from None
+        out[k] = {c: np.asarray([r[c] for r in rows],
+                                np.int64).reshape(f[k].shape)
+                  for c in rows[0]}
+        out[k]["one"] = (1.0 < f[k]).astype(np.int64)
+        out[k]["four"] = (4.0 < f[k]).astype(np.int64)
+    out["ml_thresh"] = np.clip(np.ceil(8.0 * f["ml_thresh"]),
+                               -1 << 20, 1 << 20).astype(np.int64)
+    assert set(out) == set(_CFG_I) | set(_CFG_F)
+    return jax.tree.map(jnp.asarray, out)
+
+
 def _cfg_scalars(cfg: Dict) -> Dict:
-    out = {}
-    for k in _CFG_I:
-        out[k] = jnp.asarray(cfg[k], jnp.int64)
-    for k in _CFG_F:
-        out[k] = jnp.asarray(cfg[k], jnp.float64)
-    return out
+    return _lower_cfg({k: cfg[k] for k in list(_CFG_I) + list(_CFG_F)})
 
 
 def _cfg_stack(cfgs: List[Dict]) -> Dict:
@@ -1448,12 +1572,7 @@ def _cfg_stack(cfgs: List[Dict]) -> Dict:
     power-of-two lane count (see ``params.stack_lanes``) so nearby
     batch sizes hit one compiled program."""
     arrays, _ = params_mod.stack_lanes(cfgs)
-    out = {}
-    for k in _CFG_I:
-        out[k] = jnp.asarray(arrays[k], jnp.int64)
-    for k in _CFG_F:
-        out[k] = jnp.asarray(arrays[k], jnp.float64)
-    return out
+    return _lower_cfg(arrays)
 
 
 def _check_flags(flags: int) -> None:
@@ -1465,54 +1584,111 @@ def _check_flags(flags: int) -> None:
             + ", ".join(hit))
 
 
-def _device_inputs(static: StaticConfig, prep: PreparedTrace):
-    consts = {"blk": jnp.asarray(prep.blk_tab)}
-    st0 = {k: jnp.asarray(v) for k, v in init_state(static, prep).items()}
-    xs = {k: jnp.asarray(v) for k, v in prep.xs.items()}
-    return consts, st0, xs
+def _signature(tree) -> tuple:
+    return tuple((jax.tree_util.keystr(path), a.shape, str(a.dtype))
+                 for path, a in jax.tree_util.tree_leaves_with_path(tree))
+
+
+def _execute(static: StaticConfig, preps: List[PreparedTrace],
+             cfg: Dict, tids: Optional[np.ndarray], label: str,
+             lanes: int = 1):
+    """Compile once per argument shape, then scan the prepared traces
+    chunk by chunk; returns host ``(oi, od, flags)``.  ``tids`` gives
+    each stacked lane's index into ``preps``, the first ``lanes`` of
+    them real (the rest pad the lane axis); None runs the unbatched
+    program over ``preps[0]``."""
+    n = max(p.n for p in preps)
+    C = min(CHUNK, n) if n else 1
+    m = -(-n // C) * C
+    # [T, ...] per trace, then the trace-major columns [m, T]
+    consts = {"blk": jnp.asarray(np.stack([p.blk_tab for p in preps]))}
+    cols = [p.padded(m) for p in preps]
+    xs = {k: np.stack([c[k] for c in cols], axis=1) for k in cols[0]}
+    inits = [init_state(static, p) for p in preps]
+    if tids is None:
+        st = {k: jnp.asarray(v) for k, v in inits[0].items()}
+        tid = jnp.int32(0)
+    else:
+        tid = jnp.asarray(tids, jnp.int32)
+        st = {k: jnp.stack([jnp.asarray(i[k]) for i in inits])[tid]
+              for k in inits[0]}
+    chunks = [{k: v[i:i + C] for k, v in xs.items()}
+              for i in range(0, m, C)]
+    key = (static, tids is not None,
+           _signature((consts, cfg, st, chunks[0], tid)))
+    t0 = time.perf_counter()
+    exe = _COMPILED.get(key)
+    if exe is None:
+        scan, export = _make_run(static, tids is not None)
+        exe = (scan.lower(consts, cfg, st, chunks[0], tid).compile(),
+               export.lower(st).compile())
+        _COMPILED[key] = exe
+    t1 = time.perf_counter()
+    scan, export = exe
+    for x in chunks:
+        st = scan(consts, cfg, st, {k: jnp.asarray(v) for k, v in x.items()},
+                  tid)
+    out = jax.block_until_ready(export(st))
+    t2 = time.perf_counter()
+    real = [0] if tids is None else tids[:lanes]
+    SCAN_LOG.append({"bucket": label, "lanes": len(real),
+                     "traces": len(preps), "steps": m,
+                     "accesses": sum(preps[t].n for t in real),
+                     "compile_s": t1 - t0, "scan_s": t2 - t1})
+    return tuple(np.asarray(a) for a in out)
 
 
 def run_single(sp, trace: Dict,
-               pad_to: Optional[int] = None) -> Tuple[np.ndarray,
-                                                      np.ndarray]:
+               caps: Optional[Caps] = None) -> Tuple[np.ndarray, np.ndarray]:
     """Run one config through the jax engine; returns (oi, od) in the C
     kernel's export layout (feed to native.deposit_counters)."""
-    _maybe_persistent_cache()
+    caps = Caps.cover([caps or Caps.of(trace), Caps.of(trace)])
     with _x64():
-        static, cfg = split_config(sp, _nten(trace))
-        prep = prepare_trace(static, trace, pad_to)
-        consts, st0, xs = _device_inputs(static, prep)
-        fn = _get_run(static, False)
-        oi, od, fl = fn(consts, _cfg_scalars(cfg), st0, xs)
-        oi, od, fl = np.asarray(oi), np.asarray(od), np.asarray(fl)
+        static, cfg = split_config(sp, caps.nten)
+        prep = prepare_trace(static, trace, caps)
+        oi, od, fl = _execute(static, [prep], _cfg_scalars(cfg), None,
+                              sp.name)
     _check_flags(fl)
-    return oi, od
+    return oi, od.view(np.float64)
 
 
-def run_batch(sps: List, trace: Dict,
-              pad_to: Optional[int] = None) -> List[Tuple[np.ndarray,
-                                                          np.ndarray]]:
-    """Run N configs against one trace; lanes sharing a StaticConfig
-    execute as one vmapped device program (a "shape bucket").  Results
-    come back in input order; per-lane overflow raises."""
-    _maybe_persistent_cache()
+def run_batch(sps: List, traces, caps: Optional[Caps] = None,
+              ) -> List[Tuple[np.ndarray, np.ndarray]]:
+    """Run N configs; ``traces`` is one trace for all of them or a list
+    with one trace per config.  Lanes sharing a StaticConfig execute as
+    one vmapped device program (a "shape bucket") that scans all their
+    traces side by side, each lane its own.  Results come back in input
+    order; per-lane overflow raises.
+
+    Every trace is laid out at the common extents of all of them and of
+    ``caps`` (see :class:`Caps`): pass a campaign's envelope as ``caps``
+    and each bucket compiles once for the whole campaign."""
+    if isinstance(traces, dict):
+        traces = [traces] * len(sps)
+    distinct = list({id(t): t for t in traces}.values())
+    caps = Caps.cover([Caps.of(t) for t in distinct]
+                      + ([caps] if caps else []))
     results: List = [None] * len(sps)
     with _x64():
-        nten = _nten(trace)
         groups: Dict[StaticConfig, List[tuple]] = {}
-        for i, sp in enumerate(sps):
-            static, cfg = split_config(sp, nten)
-            groups.setdefault(static, []).append((i, cfg))
+        for i, (sp, tr) in enumerate(zip(sps, traces)):
+            static, cfg = split_config(sp, caps.nten)
+            groups.setdefault(static, []).append((i, sp.name, cfg, tr))
         for static, lanes in groups.items():
-            prep = prepare_trace(static, trace, pad_to)
-            consts, st0, xs = _device_inputs(static, prep)
-            fn = _get_run(static, True)
-            cfgj = _cfg_stack([c for _, c in lanes])
-            oi, od, fl = fn(consts, cfgj, st0, xs)
-            oi, od, fl = np.asarray(oi), np.asarray(od), np.asarray(fl)
-            for j, (i, _) in enumerate(lanes):
+            trs = list({id(t): t for *_, t in lanes}.values())
+            tix = {id(t): j for j, t in enumerate(trs)}
+            preps = [prepare_trace(static, t, caps) for t in trs]
+            cfgj = _cfg_stack([c for _, _, c, _ in lanes])
+            # lanes padded to a power of two re-run lane 0
+            # (params.stack_lanes)
+            tids = [tix[id(t)] for *_, t in lanes]
+            tids += tids[:1] * (len(cfgj[_CFG_I[0]]) - len(tids))
+            oi, od, fl = _execute(static, preps, cfgj,
+                                  np.asarray(tids), lanes[0][1],
+                                  len(lanes))
+            for j, (i, *_) in enumerate(lanes):
                 _check_flags(fl[j])
-                results[i] = (oi[j], od[j])
+                results[i] = (oi[j], od[j].view(np.float64))
     return results
 
 

@@ -17,7 +17,7 @@ import hashlib
 import os
 import subprocess
 from pathlib import Path
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
@@ -200,21 +200,19 @@ def resolve_engine(requested: str = "soa") -> str:
     return requested
 
 
-def run_native(sim, trace: Dict) -> bool:
-    """Run the trace through the compiled kernel, depositing all counters
-    on ``sim`` (a SoAHierarchySim).  Returns False when the kernel is
+def run_arrays(sp, trace: Dict) -> Optional[Tuple[np.ndarray,
+                                                   np.ndarray]]:
+    """Run the trace through the compiled kernel; returns its flat
+    counter vectors ``(oi[98], od[10])``, or None when the kernel is
     unavailable or the configuration falls outside its envelope."""
-    if not getattr(sim, "native", True):
-        return False
     lib = get_lib()
     if lib is None:
-        return False
-    sp = sim.sp
+        return None
     tensor = np.ascontiguousarray(trace["tensor"], np.int32)
     nten = int(tensor.max()) + 1 if len(tensor) else 1
     packed = pack_config_sp(sp, nten)
     if packed is None:
-        return False
+        return None
     ci, cd = packed
 
     core = np.ascontiguousarray(trace["core"], np.int32)
@@ -229,7 +227,19 @@ def run_native(sim, trace: Dict) -> bool:
     lib.run_trace(ci, cd, core, pc, addr, write, tensor,
                   np.ascontiguousarray(reuse), ctypes.c_int64(len(core)),
                   oi, od)
-    deposit_counters(sim, oi, od)
+    return oi, od
+
+
+def run_native(sim, trace: Dict) -> bool:
+    """Run the trace through the compiled kernel, depositing all counters
+    on ``sim`` (a SoAHierarchySim).  Returns False when the kernel is
+    unavailable or the configuration falls outside its envelope."""
+    if not getattr(sim, "native", True):
+        return False
+    out = run_arrays(sim.sp, trace)
+    if out is None:
+        return False
+    deposit_counters(sim, *out)
     return True
 
 

@@ -158,7 +158,7 @@ def lane_pad(n: int) -> int:
     """Pad a lane count up to the next power of two so nearby batch
     sizes reuse one compiled program (B is baked into the vmapped
     executable's shapes; without padding every distinct group size
-    triggers a fresh multi-minute XLA:CPU compile)."""
+    triggers a fresh compile)."""
     if n <= 1:
         return n
     return 1 << (n - 1).bit_length()

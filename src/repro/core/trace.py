@@ -123,9 +123,10 @@ def _finish(b: _Builder) -> Dict:
            "meta": {"n_macro_ops": b.n_macro, "tensors": b.alloc.table}}
     # REPRO_TRACE_CAP=N truncates every generated trace to its first N
     # accesses.  Stream interleaving floors trace length around ~120k
-    # accesses regardless of ``scale``; the cap is how compile-dominated
-    # CI gates (the jax engine pays minutes of XLA:CPU compile per
-    # hierarchy shape) run the REAL sweep/CLI path on a bounded input.
+    # accesses regardless of ``scale``; the cap is how CI gates run the
+    # REAL sweep/CLI path of the jax engine on a bounded input (on the
+    # CPU backend one scan step of a prefetching bucket costs
+    # milliseconds, so a floor-length trace takes minutes per lane).
     # Both sides of an equivalence gate see identical capped traces, so
     # bit-identity / fingerprint comparisons are unaffected.
     cap = os.environ.get("REPRO_TRACE_CAP")

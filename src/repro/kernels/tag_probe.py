@@ -37,23 +37,29 @@ def _tag_probe_kernel(tag_ref, vld_ref, last_ref, seq_ref, q_ref, out_ref,
     seq = seq_ref[...]                       # (bb, A) int32
     q = q_ref[...]                           # (bb, 1) int32
 
-    m = vld & (tags == q)
-    hit = jnp.any(m, axis=1)
-    hitw = jnp.argmax(m, axis=1)
-    freew = jnp.argmax(~vld, axis=1)
-    full = jnp.sum(vld.astype(jnp.int32), axis=1) >= ways
+    # first-index selects as a min over way indices: Mosaic reduces
+    # int32 by min/max but has no argmax/argmin over integers
+    iota = jax.lax.broadcasted_iota(jnp.int32, tags.shape, 1)
+
+    def first(mask):                         # first true way, else A
+        return jnp.min(jnp.where(mask, iota, ways), axis=1, keepdims=True)
+
+    hitw = first(vld & (tags == q))
+    hit = hitw < ways
+    freew = first(~vld)
+    full = freew == ways
 
     # LRU among the stalest `last` stamps; fill sequence breaks ties
-    # (first index on equal seq — argmin returns the first minimum).
+    # (first index on equal seq)
     stale = last == jnp.min(last, axis=1, keepdims=True)
     big = jnp.iinfo(jnp.int32).max
-    vicw = jnp.argmin(jnp.where(stale, seq, big), axis=1)
+    sq = jnp.where(stale, seq, big)
+    vicw = first(stale & (sq == jnp.min(sq, axis=1, keepdims=True)))
 
     way = jnp.where(hit, hitw, jnp.where(full, vicw, freew))
     evict = ~hit & full
-    out_ref[...] = jnp.stack(
-        [hit.astype(jnp.int32), way.astype(jnp.int32),
-         evict.astype(jnp.int32)], axis=1)
+    out_ref[...] = jnp.concatenate(
+        [hit.astype(jnp.int32), way, evict.astype(jnp.int32)], axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("bb", "interpret"))

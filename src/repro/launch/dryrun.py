@@ -3,6 +3,8 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
 # ^ MUST precede any jax import: jax locks the device count on first init.
 #   Set here (and only here): smoke tests and benches must see 1 device.
+#   The lowering runs on the CPU host platform even where an accelerator
+#   is attached (jax_platforms below), so a dry-run never claims a chip.
 
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
@@ -53,6 +55,8 @@ from typing import Any, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
+
+jax.config.update("jax_platforms", "cpu")
 
 from repro.api.schema import (ROOFLINE_TERMS, V5E_HBM_BW, V5E_ICI_BW,
                               V5E_PEAK_FLOPS, dump_record, load_record)
@@ -309,9 +313,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     t0 = time.time()
     try:
         from repro.launch.hlo_analysis import analyze
-        # jax >= 0.5 has set_mesh; 0.4.x uses the Mesh context manager
-        set_mesh = getattr(jax.sharding, "set_mesh", None)
-        with (set_mesh(mesh) if set_mesh is not None else mesh):
+        with jax.sharding.set_mesh(mesh):
             lowered, meta = lower_cell(arch, shape_name, mesh, rc=rc)
             t_lower = time.time() - t0
             compiled = lowered.compile()

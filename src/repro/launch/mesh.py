@@ -19,13 +19,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types where the jax version has them
-    (jax >= 0.5); 0.4.x meshes are implicitly auto-partitioned."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,27 +1,34 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
 Runs the continuous-batching engine (serve/engine.py) over the smoke
-config with synthetic requests; ``--dryrun`` cells for the production
-serving shapes (prefill_32k / decode_32k / long_500k) are produced by
+config, or with ``--full`` over the published widths, with seeded random
+weights held in the compute dtype and synthetic requests.  Production
+serving shapes (prefill_32k / decode_32k / long_500k) are lowered by
 launch/dryrun.py.
 """
 
 from __future__ import annotations
 
 import argparse
+from typing import List, Tuple
 
 import jax
 import numpy as np
 
 from repro.configs.base import RunConfig
 from repro.configs.registry import ARCH_IDS, get_config
-from repro.models import model as mdl
-from repro.serve.engine import Request, ServingEngine
+from repro.runtime import compile_cache
+from repro.serve.engine import Request, ServingEngine, init_serving_params
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> Tuple[ServingEngine, List[Request]]:
+    """Serve synthetic requests; returns the engine and finished
+    requests."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="published widths instead of the reduced "
+                         "smoke config")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=12)
     ap.add_argument("--max-new", type=int, default=16)
@@ -43,9 +50,12 @@ def main(argv=None) -> None:
                     help="token id that ends a request early")
     args = ap.parse_args(argv)
 
-    cfg = get_config(args.arch, smoke=True)
+    compile_cache.configure()
+    cfg = get_config(args.arch, smoke=args.smoke)
     rc = RunConfig(remat="none")
-    params = mdl.init_params(cfg, jax.random.PRNGKey(args.seed))
+    print(f"[serve] arch={cfg.name} params={cfg.param_count():,} "
+          f"device={jax.devices()[0].device_kind}")
+    params = init_serving_params(cfg, rc, args.seed)
     engine = ServingEngine(cfg, rc, params, batch_slots=args.slots,
                            max_seq=args.prompt_len + args.max_new + 8,
                            page_size=args.page_size,
@@ -73,6 +83,7 @@ def main(argv=None) -> None:
           f"{pg.hbm.n_pages} pages in use, host "
           f"{pg.host.n_pages - pg.host.n_free}/{pg.host.n_pages} — "
           f"page size {pg.page_size} tokens")
+    return engine, done
 
 
 if __name__ == "__main__":

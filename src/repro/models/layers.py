@@ -35,22 +35,9 @@ SEQ_PARALLEL = False
 
 
 def ambient_mesh():
-    """The active mesh, or None.  Version-tolerant: newer jax exposes
-    ``jax.sharding.get_abstract_mesh`` (set_mesh contexts); jax 0.4.x
-    tracks the ambient ``with mesh:`` physical mesh on thread_resources."""
-    gam = getattr(jax.sharding, "get_abstract_mesh", None)
-    if gam is not None:
-        m = gam()
-        if m is not None and not m.empty:
-            return m
-    try:
-        from jax._src import mesh as _mesh_lib
-        m = _mesh_lib.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+    """The mesh installed by ``jax.sharding.set_mesh``, or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def constrain(x: jax.Array, *spec) -> jax.Array:

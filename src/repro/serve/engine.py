@@ -23,8 +23,18 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, RunConfig
 from repro.models import model as mdl
-from repro.serve.steps import build_decode_step, build_prefill_step
+from repro.serve.steps import (build_decode_step, build_prefill_step,
+                               cast_floating)
 from repro.tpu.kv_cache import PagedKVManager
+
+
+def init_serving_params(cfg: ModelConfig, rc: RunConfig, seed: int):
+    """Seeded weights, made on the device directly in the compute dtype:
+    serving keeps no f32 master copy (at published widths the f32 tree
+    alone would take most of one chip's HBM)."""
+    return jax.jit(lambda key: cast_floating(mdl.init_params(cfg, key),
+                                             rc.compute_dtype))(
+        jax.random.PRNGKey(seed))
 
 
 @dataclasses.dataclass
@@ -50,7 +60,8 @@ class ServingEngine:
                  request_ttl_steps: Optional[int] = None):
         self.cfg = cfg
         self.rc = rc
-        self.params = params
+        # served weights live in the compute dtype (no master copy)
+        self.params = cast_floating(params, rc.compute_dtype)
         self.slots = batch_slots
         self.max_seq = max_seq
         self.greedy = greedy

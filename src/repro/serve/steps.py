@@ -22,6 +22,16 @@ from repro.dist import sharding as shd
 from repro.models import model as mdl
 
 
+def cast_floating(params, dtype) -> Any:
+    """Floating leaves in ``dtype``; leaves already in it stay as they
+    are, so weights held in the compute dtype cost no per-call cast."""
+    dtype = jnp.dtype(dtype)
+    return jax.tree.map(
+        lambda p: p.astype(dtype)
+        if jnp.issubdtype(p.dtype, jnp.floating) and p.dtype != dtype
+        else p, params)
+
+
 def build_prefill_step(cfg: ModelConfig, rc: RunConfig, max_seq: int):
     """(params, tokens[, img_embed]) → (last_logits, cache).
 
@@ -43,9 +53,7 @@ def build_prefill_step(cfg: ModelConfig, rc: RunConfig, max_seq: int):
     def prefill_step(params, tokens, img_embed=None):
         B = tokens.shape[0]
         nch = max(1, rc.prefill_chunks)
-        params_c = jax.tree.map(
-            lambda p: p.astype(cdt)
-            if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
+        params_c = cast_floating(params, cdt)
         cache = mdl.init_cache(cfg, B, max_seq, dtype=cdt,
                                img_tokens=cfg.n_img_tokens)
         if nch <= 1 or B % nch:
@@ -92,9 +100,7 @@ def build_decode_step(cfg: ModelConfig, rc: RunConfig):
     cdt = jnp.dtype(rc.compute_dtype)
 
     def decode_step(params, cache, tokens):
-        params_c = jax.tree.map(
-            lambda p: p.astype(cdt)
-            if jnp.issubdtype(p.dtype, jnp.floating) else p, params)
+        params_c = cast_floating(params, cdt)
         logits, cache, _ = mdl.forward(params_c, cfg, rc, tokens,
                                        cache=cache)
         return logits[:, 0], cache

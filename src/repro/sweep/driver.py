@@ -76,7 +76,8 @@ def run_config_sweep(configs: Sequence[SystemParams], scale: float = 1.0,
                      cell_timeout: Optional[float] = None,
                      journal_path: Optional[Path] = None,
                      resume: bool = False,
-                     backend: str = "pool") -> List[Dict[str, Any]]:
+                     backend: str = "pool",
+                     runner: Any = None) -> List[Dict[str, Any]]:
     """Run every config over the workload suite; one aggregate per config.
 
     Returns, in input order::
@@ -86,7 +87,9 @@ def run_config_sweep(configs: Sequence[SystemParams], scale: float = 1.0,
 
     The resilience knobs (``retries`` / ``cell_timeout`` /
     ``journal_path`` + ``resume`` / ``strict=False`` degradation) pass
-    straight through to ``Runner.run_configs``.
+    straight through to ``Runner.run_configs``; ``runner`` supplies a
+    configured :class:`~repro.api.runner.Runner` in place of a default
+    one.
     """
     # lazy: this module loads with the sweep package __init__; the
     # Runner (and its multiprocessing machinery) only at execution time
@@ -104,7 +107,7 @@ def run_config_sweep(configs: Sequence[SystemParams], scale: float = 1.0,
 
     fresh: Dict[Tuple, Dict[str, Any]] = {}
     if todo:
-        rows = Runner(processes=processes).run_configs(
+        rows = (runner or Runner(processes=processes)).run_configs(
             todo, workloads=workloads, scale=scale, engine=engine,
             native=native, strict=strict, retries=retries,
             cell_timeout=cell_timeout, journal_path=journal_path,
@@ -141,7 +144,9 @@ def run_ladder_sweep(points: Sequence[Mapping[str, Any]],
                      cell_timeout: Optional[float] = None,
                      journal_path: Optional[Path] = None,
                      resume: bool = False,
-                     backend: str = "pool") -> Dict[str, Any]:
+                     backend: str = "pool",
+                     workloads: Optional[Sequence[str]] = None,
+                     runner: Any = None) -> Dict[str, Any]:
     """Evaluate the paper's four-row ladder for every grid point.
 
     Returns an artifact-shaped dict: per point the four row aggregates,
@@ -176,7 +181,8 @@ def run_ladder_sweep(points: Sequence[Mapping[str, Any]],
                                strict=False, retries=retries,
                                cell_timeout=cell_timeout,
                                journal_path=journal_path, resume=resume,
-                               backend=backend)
+                               backend=backend, workloads=workloads,
+                               runner=runner)
 
     # structured failure rows, deduped (aliased configs share them)
     failures: List[Dict[str, Any]] = []

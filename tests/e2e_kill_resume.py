@@ -20,15 +20,13 @@ directly, and tests/test_chaos.py wraps it as a pytest case.  The
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 import tempfile
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parents[1]
-JOURNAL_DIR = REPO / "artifacts" / "sweep"
-# a scale no other entry point uses, so the campaign hash (and journal
-# name) cannot collide with a real sweep run
 SWEEP_ARGS = ["sweep", "--smoke", "--scale", "0.004"]
 KILL_AFTER = 9
 
@@ -55,22 +53,31 @@ def load(path):
 
 def main():
     tmp = Path(tempfile.mkdtemp(prefix="kill_resume_"))
+    try:
+        drill(tmp)
+    finally:
+        shutil.rmtree(tmp, ignore_errors=True)
+
+
+def drill(tmp):
     ref_path = tmp / "reference.json"
     res_path = tmp / "resumed.json"
-    for stale in JOURNAL_DIR.glob("*.journal.jsonl"):
-        stale.unlink()
+    # a private journal directory: nothing else writes there, and the
+    # drill leaves nothing in the repository's artifacts/
+    journal_dir = tmp / "journal"
+    sweep_args = SWEEP_ARGS + ["--journal-dir", str(journal_dir)]
 
     print("[e2e] 1/3 undisturbed reference run", flush=True)
-    rc = run_cli(SWEEP_ARGS + ["--out", str(ref_path)])
+    rc = run_cli(sweep_args + ["--out", str(ref_path)])
     assert rc == 0, f"reference run failed: exit {rc}"
-    assert not list(JOURNAL_DIR.glob("*.journal.jsonl")), \
+    assert not list(journal_dir.glob("*.journal.jsonl")), \
         "journal must retire after a fully-successful campaign"
 
     print(f"[e2e] 2/3 kill -9 after {KILL_AFTER} cells", flush=True)
-    rc = run_cli(SWEEP_ARGS + ["--out", str(tmp / "never_written.json")],
+    rc = run_cli(sweep_args + ["--out", str(tmp / "never_written.json")],
                  chaos={"seed": 5, "kill_after_cells": KILL_AFTER})
     assert rc == 137, f"expected hard-kill exit 137, got {rc}"
-    journals = list(JOURNAL_DIR.glob("*.journal.jsonl"))
+    journals = list(journal_dir.glob("*.journal.jsonl"))
     assert len(journals) == 1, f"expected one orphan journal: {journals}"
     n_done = len(journals[0].read_text().splitlines()) - 1  # minus header
     assert n_done == KILL_AFTER, \
@@ -79,9 +86,9 @@ def main():
         "killed run must not emit an artifact"
 
     print("[e2e] 3/3 --resume from the orphan journal", flush=True)
-    rc = run_cli(SWEEP_ARGS + ["--resume", "--out", str(res_path)])
+    rc = run_cli(sweep_args + ["--resume", "--out", str(res_path)])
     assert rc == 0, f"resume run failed: exit {rc}"
-    assert not list(JOURNAL_DIR.glob("*.journal.jsonl")), \
+    assert not list(journal_dir.glob("*.journal.jsonl")), \
         "journal must retire after the resumed campaign completes"
 
     ref_fp, ref_rows, ref_result = load(ref_path)

@@ -197,6 +197,40 @@ class TestGracefulDegradation:
             {fr["config"] for fr in failures})
         assert "fingerprint" in art["provenance"]
 
+    def test_degraded_table_exits_nonzero(self, tmp_path, monkeypatch):
+        """`repro table` still writes the marked artifact of a degraded
+        campaign, but exits 1: a failed cell must fail the command."""
+        from repro.cli import main
+        from repro.core import trace as trace_mod
+        keys = [f"{config_hash(PRESETS['baseline'])}:{wl}"
+                for wl in trace_mod.WORKLOADS]
+        seed = next(s for s in range(200) if 0 < sum(
+            FaultSpec(seed=s, p_crash=0.5).draw(k, 0) == "crash"
+            for k in keys) < len(keys))
+        monkeypatch.setenv("REPRO_CHAOS", json.dumps(
+            {"seed": seed, "p_crash": 0.5}))
+        out = tmp_path / "table.json"
+        rc = main(["table", "--scale", str(TINY), "--preset", "baseline",
+                   "--processes", "1", "--retries", "0", "--out", str(out),
+                   "--journal-dir", str(tmp_path)])
+        assert rc == 1
+        art = json.loads(out.read_text())
+        assert art["provenance"]["failures"] and art["rows"]
+
+    def test_jax_engine_never_fans_out(self, monkeypatch):
+        """`--engine jax` under the pool backend runs its cells in the
+        coordinator: pool workers would each claim the one chip."""
+        monkeypatch.setenv("REPRO_TRACE_CAP", "600")
+
+        def no_pool(*_a, **_k):
+            raise AssertionError("jax-engine cells reached the pool")
+
+        monkeypatch.setattr(Runner, "_execute_pool", no_pool)
+        res = Runner(processes=4).run_configs(
+            [PRESETS["baseline"]], workloads=["cnn", "rnn"],
+            scale=0.0123, engine="jax")
+        assert set(res[0]["rows"]) == {"cnn", "rnn"}
+
     def test_all_cells_failed_raises(self):
         exp = Experiment(name="doomed", workloads=("cnn",),
                          scale=TINY, processes=1)

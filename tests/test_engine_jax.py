@@ -58,6 +58,25 @@ def test_run_batch_mixed_shape_buckets(tiny_trace):
         assert np.array_equal(od, od1), sp.name
 
 
+def test_run_batch_lanes_over_traces(tiny_trace):
+    """Lanes that scan different traces of unequal length in one
+    program (the shorter padded with no-op steps) == each config run
+    alone on its own trace; a bucket with one lane runs beside them."""
+    from repro.core import engine_jax
+    rnn = trace_mod.WORKLOADS["rnn"](scale=0.012)
+    short = {k: (v[:N // 2] if k in ("core", "pc", "addr", "write",
+                                     "tensor", "reuse") else v)
+             for k, v in rnn.items()}
+    sps = [BASELINE, apply_point(BASELINE, {"l2.hit_latency": 19}),
+           BASELINE, SHARED_L3]
+    trs = [tiny_trace, tiny_trace, short, short]
+    batch = engine_jax.run_batch(sps, trs)
+    for sp, tr, (oi, od) in zip(sps, trs, batch):
+        oi1, od1 = engine_jax.run_single(sp, tr)
+        assert np.array_equal(oi, oi1), sp.name
+        assert np.array_equal(od, od1), sp.name
+
+
 def test_batch_metrics_match_soa_engine(tiny_trace):
     """metrics_from_outputs on a batch lane == the drop-in
     JaxHierarchySim.run row for the same config."""
@@ -70,6 +89,36 @@ def test_batch_metrics_match_soa_engine(tiny_trace):
     want = HierarchySim(BASELINE, engine="jax").run(tiny_trace)
     for f in dataclasses.fields(want):
         assert getattr(got, f.name) == getattr(want, f.name), f.name
+
+
+def test_chunked_scan_matches_one_scan(tiny_trace, monkeypatch):
+    """A trace scanned in chunks — the state carried on the device from
+    call to call, the tail padded with no-op steps — gives the counters
+    of one scan over the whole trace."""
+    from repro.core import engine_jax
+    sps = [BASELINE, SHARED_L3]
+    whole = engine_jax.run_batch(sps, tiny_trace)
+    monkeypatch.setattr(engine_jax, "CHUNK", 512)
+    chunked = engine_jax.run_batch(sps, tiny_trace)
+    for sp, (oi, od), (oi1, od1) in zip(sps, whole, chunked):
+        assert np.array_equal(oi, oi1), sp.name
+        assert np.array_equal(od, od1), sp.name
+
+
+def test_caps_envelope_leaves_counters_unchanged(tiny_trace):
+    """Laying a trace out at a campaign's larger extents (what lets all
+    its traces share one compiled program per bucket) changes nothing."""
+    import dataclasses
+
+    from repro.core import engine_jax
+    caps = engine_jax.Caps.of(tiny_trace)
+    wide = dataclasses.replace(caps, blk=2 * caps.blk, pg=2 * caps.pg,
+                               n_pc=caps.n_pc + 3, mk=2 * caps.mk,
+                               nten=caps.nten + 2)
+    (oi, od), = engine_jax.run_batch([SHARED_L3], tiny_trace)
+    (oi1, od1), = engine_jax.run_batch([SHARED_L3], tiny_trace, caps=wide)
+    assert np.array_equal(oi, oi1)
+    assert np.array_equal(od, od1)
 
 
 # ---------------------------------------------------------------- Pallas

@@ -88,11 +88,9 @@ def test_collective_bytes_all_reduce():
         from jax.sharding import PartitionSpec as P, NamedSharding
         import sys; sys.path.insert(0, "src")
         from repro.launch.hlo_analysis import analyze
-        at = getattr(jax.sharding, "AxisType", None)
-        mesh = (jax.make_mesh((8,), ("x",), axis_types=(at.Auto,))
-                if at is not None else jax.make_mesh((8,), ("x",)))
-        set_mesh = getattr(jax.sharding, "set_mesh", None)
-        with (set_mesh(mesh) if set_mesh is not None else mesh):
+        mesh = jax.make_mesh((8,), ("x",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
+        with jax.sharding.set_mesh(mesh):
             f = jax.jit(lambda a, b: a @ b,
                         in_shardings=(NamedSharding(mesh, P(None, "x")),
                                       NamedSharding(mesh, P("x", None))),

@@ -115,3 +115,19 @@ def test_train_loop_preemption_and_resume(tmp_path):
     r_ref = train(cfg, rc, batch=4, seq=16, steps=12, log_every=1000)
     np.testing.assert_allclose(r2.losses[-1], r_ref.losses[-1],
                                rtol=5e-2)
+
+
+class TestCompileCache:
+    def test_env_dir_is_honoured(self, tmp_path, monkeypatch):
+        from repro.runtime import compile_cache
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.configure() == str(tmp_path)
+
+    def test_default_dir_is_fixed_and_gitignored(self):
+        from pathlib import Path
+
+        from repro.runtime import compile_cache
+        repo = Path(__file__).resolve().parents[1]
+        assert compile_cache.DEFAULT_DIR == repo / ".jax_cache"
+        ignored = (repo / ".gitignore").read_text().split()
+        assert ".jax_cache/" in ignored

@@ -133,15 +133,31 @@ def test_native_kernel_bit_identical(workload, reference):
     _check(tr, reference, native=True)
 
 
+def _prefix(tr):
+    sub = dict(tr)
+    for k in ("core", "pc", "addr", "write", "tensor", "reuse"):
+        sub[k] = tr[k][:JAX_SLICE]
+    return sub
+
+
+@pytest.fixture(scope="module")
+def jax_caps():
+    """One layout for the three workload prefixes, as a batched campaign
+    lays out its traces: each preset compiles once for all three."""
+    pytest.importorskip("jax")
+    from repro.core import engine_jax
+    return engine_jax.Caps.cover([
+        engine_jax.Caps.of(_prefix(gen(scale=SCALE)))
+        for gen in trace_mod.WORKLOADS.values()])
+
+
 @pytest.fixture(scope="module")
 def jax_reference(workload):
     """Counters+metrics on the JAX_SLICE prefix via the SoA engine —
     itself asserted bit-identical to the object reference above, so the
     jax leg inherits the full chain jax == soa == object."""
     _, tr = workload
-    sub = dict(tr)
-    for k in ("core", "pc", "addr", "write", "tensor", "reuse"):
-        sub[k] = tr[k][:JAX_SLICE]
+    sub = _prefix(tr)
     out = {}
     for sp in CONFIGS:
         sim = HierarchySim(sp, engine="soa")
@@ -150,14 +166,18 @@ def jax_reference(workload):
     return sub, out
 
 
-def test_jax_engine_bit_identical(workload, jax_reference):
-    """Functional jax scan engine — every preset, bit-identical
-    counters and Metrics floats on the shared trace prefix."""
-    pytest.importorskip("jax")
+def test_jax_engine_bit_identical(workload, jax_reference, jax_caps):
+    """Functional jax scan engine, batched as a campaign runs it —
+    every preset, bit-identical counters and Metrics floats on the
+    shared trace prefix."""
+    from repro.core import engine_jax, native
+    from repro.core.engine_soa import SoAHierarchySim
     sub, want = jax_reference
-    for sp in CONFIGS:
-        sim = HierarchySim(sp, engine="jax")
-        metrics = sim.run(sub)
+    outs = engine_jax.run_batch(CONFIGS, sub, caps=jax_caps)
+    for sp, (oi, od) in zip(CONFIGS, outs):
+        metrics = engine_jax.metrics_from_outputs(sp, sub, oi, od)
+        sim = SoAHierarchySim(sp)
+        native.deposit_counters(sim, oi, od)
         want_ctr, want_metrics = want[sp.name]
         got_ctr = _counters_soa(sim)
         assert got_ctr == want_ctr, (sp.name, {
