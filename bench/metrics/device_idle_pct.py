@@ -1,0 +1,8 @@
+"""Share of the traced window in which the device ran no operation."""
+
+
+def read(run):
+    p = run.profile
+    if p is None or not p.window_s:
+        return None
+    return 100.0 * (1.0 - p.busy_s / p.window_s)
