@@ -22,77 +22,6 @@ BENCH_PATH = Path(__file__).resolve().parents[3] / "BENCH_sim.json"
 BENCH_CANONICAL_SCALE = 0.05
 
 
-def _slice_trace(tr: Dict, n: int) -> Dict:
-    out = dict(tr)
-    for k in ("core", "pc", "addr", "write", "tensor", "reuse"):
-        out[k] = tr[k][:n]
-    return out
-
-
-def bench_jax(tr: Dict, scale: float, workload: str,
-              single_n: int = 4096, batch_n: int = 2048,
-              big_batch_n: int = 512) -> List[Dict]:
-    """jax-engine rows: single-config throughput plus batched-sweep
-    throughput — ``configs_per_sec`` at 32 and 256 design points, each
-    batch one vmapped device program.
-
-    The scan's per-access cost is length-independent after compile, so
-    each row runs a bounded slice of the trace and reports steady-state
-    accesses/sec (and, for batches, configs/sec over that slice);
-    ``accesses`` records the slice actually timed.  The big batch gets
-    the shortest slice — on a CPU device vmap lanes are executed
-    sequentially, so its wall cost scales with batch size.
-    """
-    import jax
-
-    from repro.core import engine_jax
-    from repro.core.presets import BASELINE
-    from repro.sweep.grid import apply_point
-
-    dev = jax.devices()[0]
-    records: List[Dict] = []
-
-    def lanes(b: int) -> List:
-        # distinct configs in one shape bucket: the L2 hit latency is a
-        # vmapped scalar, so every lane still shares the compiled code
-        return [apply_point(BASELINE, {"l2.hit_latency": 12 + i})
-                for i in range(b)]
-
-    for label, sps, n in (
-            ("jax", [BASELINE], single_n),
-            ("jax_batch32", lanes(32), batch_n),
-            ("jax_batch256", lanes(256), big_batch_n)):
-        sub = _slice_trace(tr, n)
-        t0 = time.perf_counter()
-        if len(sps) == 1:
-            engine_jax.run_single(sps[0], sub)
-        else:
-            engine_jax.run_batch(sps, sub)
-        cold = time.perf_counter() - t0
-        t0 = time.perf_counter()           # warm: compile cache hit
-        if len(sps) == 1:
-            engine_jax.run_single(sps[0], sub)
-        else:
-            engine_jax.run_batch(sps, sub)
-        dt = time.perf_counter() - t0
-        records.append({
-            "name": f"sim_{label}",
-            "engine": "jax",
-            "platform": dev.platform,
-            "device_kind": dev.device_kind,
-            "native": False,
-            "config": BASELINE.name,
-            "workload": workload,
-            "scale": scale,
-            "batch": len(sps),
-            "accesses": len(sub["core"]) * len(sps),
-            "accesses_per_sec": round(len(sub["core"]) * len(sps) / dt, 1),
-            "configs_per_sec": round(len(sps) / dt, 2),
-            "compile_s": round(cold - dt, 1),
-        })
-    return records
-
-
 def bench_engines(scale: float = 0.05, workload: str = "cnn",
                   save: bool = True, repeats: int = 2,
                   native: bool = True) -> List[Dict]:
@@ -132,7 +61,6 @@ def bench_engines(scale: float = 0.05, workload: str = "cnn",
                 "accesses": n,
                 "accesses_per_sec": round(n / dt, 1),
             })
-    records.extend(bench_jax(tr, scale=scale, workload=workload))
     agg = {
         "name": "sim_engine_speedup",
         "workload": workload,

@@ -592,8 +592,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                         "throughput bench always measures both)")
     b.add_argument("--backend", default="pool",
                    choices=["pool", "batched"],
-                   help="execution backend for the --smoke sweep gate "
-                        "and the jax rows of the throughput bench")
+                   help="execution backend for the --smoke sweep gate")
     b.add_argument("--processes", type=int, default=None,
                    help="worker processes for the --smoke gates")
     b.add_argument("--no-native", action="store_true",

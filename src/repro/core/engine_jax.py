@@ -45,7 +45,6 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -58,6 +57,7 @@ from repro.core import native as _native
 from repro.core import params as params_mod
 from repro.core.params import LINE_SIZE, PAGE_SIZE
 from repro.runtime import compile_cache
+from repro.runtime.spans import span
 
 compile_cache.configure()
 
@@ -385,16 +385,17 @@ def _trace_digest(trace: Dict) -> str:
 
 def prepare_trace(static: StaticConfig, trace: Dict,
                   caps: Caps) -> PreparedTrace:
-    key = (_trace_digest(trace), caps,
-           static.pf_on, static.ml_on, static.ml_tsize, static.st_tsize,
-           static.hybrid, static.hbm_pages_max)
-    hit = _PREP_CACHE.get(key)
-    if hit is None:
-        hit = PreparedTrace(static, trace, caps)
-        if len(_PREP_CACHE) > 32:
-            _PREP_CACHE.clear()
-        _PREP_CACHE[key] = hit
-    return hit
+    with span("prepare_trace"):
+        key = (_trace_digest(trace), caps,
+               static.pf_on, static.ml_on, static.ml_tsize, static.st_tsize,
+               static.hybrid, static.hbm_pages_max)
+        hit = _PREP_CACHE.get(key)
+        if hit is None:
+            hit = PreparedTrace(static, trace, caps)
+            if len(_PREP_CACHE) > 32:
+                _PREP_CACHE.clear()
+            _PREP_CACHE[key] = hit
+        return hit
 
 
 # ---------------------------------------------------------------------------
@@ -778,9 +779,10 @@ def _make_step(S: StaticConfig):
                 jnp.where(m, b_, st[lv + "_bkt"][inst]))
 
         def ta_hit(lv, inst, pred, t):
-            st[lv + "_hit"] = st[lv + "_hit"].at[inst, t].add(
-                jnp.where(pred, 1, 0))
-            ta_bucket_upd(lv, inst, pred, t, jnp.bool_(False))
+            with jax.named_scope("hermes.ta_shadow"):
+                st[lv + "_hit"] = st[lv + "_hit"].at[inst, t].add(
+                    jnp.where(pred, 1, 0))
+                ta_bucket_upd(lv, inst, pred, t, jnp.bool_(False))
 
         def ta_fill(lv, inst, pred, t, blk):
             st[lv + "_fil"] = st[lv + "_fil"].at[inst, t].add(
@@ -885,7 +887,8 @@ def _make_step(S: StaticConfig):
             st[lv + "_ctr"] = ctr + jnp.where(pred, 1, 0)
             st[lv + "_pf"] = st[lv + "_pf"] + jnp.where(pred & prefd, 1, 0)
             if ta_on:
-                ta_fill(lv, inst, pred, ten, blk)
+                with jax.named_scope("hermes.ta_shadow"):
+                    ta_fill(lv, inst, pred, ten, blk)
             return victim, vdirty, vaddr
 
         # ---- memory channels + hybrid page heat -------------------------
@@ -1011,17 +1014,18 @@ def _make_step(S: StaticConfig):
         def fill_shared(pred, blk, ten, reu, now, is_w):
             if not S.has_l3:
                 return
-            if S.ta3:
-                byp = ((reu == 0) & ~is_w
-                       & util_lt(st["l3_utn"][0, ten], st["l3_utd"][0, ten],
-                                 cfg["ta_bypass"]))
-            else:
-                byp = jnp.bool_(False)
-            ins = pred & ~byp
-            s3 = blk & (S3 - 1)
-            _, vd, va = c_insert("l3", ins, s3, s3, blk >> s3b, blk, ten,
-                                 reu, now, jnp.bool_(False),
-                                 jnp.bool_(False), i64(0))
+            with jax.named_scope("hermes.l3"):
+                if S.ta3:
+                    byp = ((reu == 0) & ~is_w
+                           & util_lt(st["l3_utn"][0, ten],
+                                     st["l3_utd"][0, ten], cfg["ta_bypass"]))
+                else:
+                    byp = jnp.bool_(False)
+                ins = pred & ~byp
+                s3 = blk & (S3 - 1)
+                _, vd, va = c_insert("l3", ins, s3, s3, blk >> s3b, blk,
+                                     ten, reu, now, jnp.bool_(False),
+                                     jnp.bool_(False), i64(0))
             wb(vd, now, va)
 
         def fill_private(pred, rr, blk, ten, reu, now, is_w):
@@ -1265,172 +1269,183 @@ def _make_step(S: StaticConfig):
             return emit, (blkm + best) * 64
 
         # ================================================================
-        # the access itself
+        # the access itself, one named scope per modelled component (the
+        # scopes are metadata: the compiled program is the same without)
         # ================================================================
-        act0 = x["valid"]
-        rr = x["r"]
-        now = st["time"][rr]
-        w = x["w"]
-        a = x["a"]
-        ten = x["ten"]
-        reu = x["reu"]
-        blk = a >> 6
-        t1 = blk >> s1b
-        s1 = blk & (S1 - 1)
-        si1 = rr * S1 + s1
-        lat = cfg["hl1"]
-
-        # ---- L1 ----
-        hit1, w1, _ = c_probe("l1", si1, t1)
-        h1p = act0 & hit1
-        sl1 = si1 * A1 + w1
-        st["l1h"] = st["l1h"].at[rr].add(jnp.where(h1p, 1, 0))
-        if S.ta1:
-            ta_hit("l1", rr, h1p, st["l1n"][sl1])
-        pu1 = h1p & st["l1p"][sl1]
-        st["l1pu"] = st["l1pu"].at[rr].add(jnp.where(pu1, 1, 0))
-        st["l1p"] = st["l1p"].at[sl1].set(
-            jnp.where(pu1, False, st["l1p"][sl1]))
-        st["l1l"] = st["l1l"].at[sl1].set(
-            jnp.where(h1p, now, st["l1l"][sl1]))
-        st["l1d"] = st["l1d"].at[sl1].set(
-            jnp.where(h1p & w, True, st["l1d"][sl1]))
-        pw1 = h1p & (st["l1r"][sl1] > now)
-        lat = jnp.where(pw1, fb.add(lat, promote_wait(
-            "l1", pw1, sl1, x["pg_slot"], now)), lat)
-        miss1 = act0 & ~hit1
-        st["l1m"] = st["l1m"].at[rr].add(jnp.where(miss1, 1, 0))
+        scope = jax.named_scope
+        with scope("hermes.l1"):
+            act0 = x["valid"]
+            rr = x["r"]
+            now = st["time"][rr]
+            w = x["w"]
+            a = x["a"]
+            ten = x["ten"]
+            reu = x["reu"]
+            blk = a >> 6
+            t1 = blk >> s1b
+            s1 = blk & (S1 - 1)
+            si1 = rr * S1 + s1
+            lat = cfg["hl1"]
+            hit1, w1, _ = c_probe("l1", si1, t1)
+            h1p = act0 & hit1
+            sl1 = si1 * A1 + w1
+            st["l1h"] = st["l1h"].at[rr].add(jnp.where(h1p, 1, 0))
+            if S.ta1:
+                ta_hit("l1", rr, h1p, st["l1n"][sl1])
+            pu1 = h1p & st["l1p"][sl1]
+            st["l1pu"] = st["l1pu"].at[rr].add(jnp.where(pu1, 1, 0))
+            st["l1p"] = st["l1p"].at[sl1].set(
+                jnp.where(pu1, False, st["l1p"][sl1]))
+            st["l1l"] = st["l1l"].at[sl1].set(
+                jnp.where(h1p, now, st["l1l"][sl1]))
+            st["l1d"] = st["l1d"].at[sl1].set(
+                jnp.where(h1p & w, True, st["l1d"][sl1]))
+            pw1 = h1p & (st["l1r"][sl1] > now)
+            lat = jnp.where(pw1, fb.add(lat, promote_wait(
+                "l1", pw1, sl1, x["pg_slot"], now)), lat)
+            miss1 = act0 & ~hit1
+            st["l1m"] = st["l1m"].at[rr].add(jnp.where(miss1, 1, 0))
 
         # ---- prefetcher observation (on L1 miss) ----
         if S.pf_on:
-            issue, nst = stride_observe(miss1, rr, x["pc"], a)
-            if S.ml_on:
-                emit_ml, tgt_ml = ml_observe(miss1, rr, x["pc"],
-                                             x["f1"], a)
-        lat = jnp.where(miss1, fb.add(lat, cfg["hl2"]), lat)
+            with scope("hermes.prefetch_observe"):
+                issue, nst = stride_observe(miss1, rr, x["pc"], a)
+                if S.ml_on:
+                    emit_ml, tgt_ml = ml_observe(miss1, rr, x["pc"],
+                                                 x["f1"], a)
 
-        # ---- L2 ----
-        s2 = blk & (S2 - 1)
-        t2 = blk >> s2b
-        si2 = rr * S2 + s2
-        hit2, w2, _ = c_probe("l2", si2, t2)
-        h2p = miss1 & hit2
-        sl2 = si2 * A2 + w2
-        st["l2h"] = st["l2h"].at[rr].add(jnp.where(h2p, 1, 0))
-        if S.ta2:
-            ta_hit("l2", rr, h2p, st["l2n"][sl2])
-        pu2 = h2p & st["l2p"][sl2]
-        st["l2pu"] = st["l2pu"].at[rr].add(jnp.where(pu2, 1, 0))
-        st["l2p"] = st["l2p"].at[sl2].set(
-            jnp.where(pu2, False, st["l2p"][sl2]))
-        st["l2l"] = st["l2l"].at[sl2].set(
-            jnp.where(h2p, now, st["l2l"][sl2]))
-        st["l2d"] = st["l2d"].at[sl2].set(
-            jnp.where(h2p & w, True, st["l2d"][sl2]))
-        pw2 = h2p & (st["l2r"][sl2] > now)
-        lat = jnp.where(pw2, fb.add(lat, promote_wait(
-            "l2", pw2, sl2, x["pg_slot"], now)), lat)
-        # L2 hit copies into L1 (victim writeback dropped, C semantics)
-        c_insert("l1", h2p, si1, s1, t1, blk, ten, reu, now, w,
-                 jnp.bool_(False), i64(0))
-        miss2 = miss1 & ~hit2
-        st["l2m"] = st["l2m"].at[rr].add(jnp.where(miss2, 1, 0))
+        with scope("hermes.l2"):
+            lat = jnp.where(miss1, fb.add(lat, cfg["hl2"]), lat)
+            s2 = blk & (S2 - 1)
+            t2 = blk >> s2b
+            si2 = rr * S2 + s2
+            hit2, w2, _ = c_probe("l2", si2, t2)
+            h2p = miss1 & hit2
+            sl2 = si2 * A2 + w2
+            st["l2h"] = st["l2h"].at[rr].add(jnp.where(h2p, 1, 0))
+            if S.ta2:
+                ta_hit("l2", rr, h2p, st["l2n"][sl2])
+            pu2 = h2p & st["l2p"][sl2]
+            st["l2pu"] = st["l2pu"].at[rr].add(jnp.where(pu2, 1, 0))
+            st["l2p"] = st["l2p"].at[sl2].set(
+                jnp.where(pu2, False, st["l2p"][sl2]))
+            st["l2l"] = st["l2l"].at[sl2].set(
+                jnp.where(h2p, now, st["l2l"][sl2]))
+            st["l2d"] = st["l2d"].at[sl2].set(
+                jnp.where(h2p & w, True, st["l2d"][sl2]))
+            pw2 = h2p & (st["l2r"][sl2] > now)
+            lat = jnp.where(pw2, fb.add(lat, promote_wait(
+                "l2", pw2, sl2, x["pg_slot"], now)), lat)
+            # L2 hit copies into L1 (victim writeback dropped, C semantics)
+            c_insert("l1", h2p, si1, s1, t1, blk, ten, reu, now, w,
+                     jnp.bool_(False), i64(0))
+            miss2 = miss1 & ~hit2
+            st["l2m"] = st["l2m"].at[rr].add(jnp.where(miss2, 1, 0))
 
         # ---- prefetch issue (on L2 miss) ----
         if S.pf_on:
-            rolled(S.st_deg, lambda k: do_prefetch(
-                miss2 & issue, rr, a + nst * (k + 1), ten, reu, now, True))
-            if S.ml_on:
-                do_prefetch(miss2 & emit_ml, rr, tgt_ml, ten, reu, now,
-                            False)
+            with scope("hermes.prefetch_issue"):
+                rolled(S.st_deg, lambda k: do_prefetch(
+                    miss2 & issue, rr, a + nst * (k + 1), ten, reu, now,
+                    True))
+                if S.ml_on:
+                    do_prefetch(miss2 & emit_ml, rr, tgt_ml, ten, reu, now,
+                                False)
 
         # ---- coherence (leaving the private domain) ----
         served = jnp.bool_(False)
         if S.mesi:
-            dslot = x["blk_slot"]
-            bit = i64(1) << rr
-            m0 = st["dirm"][dslot]
-            o0 = st["diro"][dslot]
-            bw_ = miss2 & w
-            br_ = miss2 & ~w
-            others = m0 & ~bit
-            ninv = popcount(others)
-            st["dinv"] = st["dinv"] + jnp.where(bw_, ninv, 0)
-            st["dupg"] = st["dupg"] + jnp.where(
-                bw_ & ((m0 & bit) != 0) & (o0 != rr), 1, 0)
-            prov = br_ & (o0 >= 0) & (o0 != rr)
-            st["dc2c"] = st["dc2c"] + jnp.where(prov, 1, 0)
-            m_r = m0 | bit
-            o_r = jnp.where(prov, i64(-1), o0)
-            o_r = jnp.where((m_r == bit) & ~prov, rr, o_r)
-            st["dirm"] = st["dirm"].at[dslot].set(
-                jnp.where(bw_, bit, jnp.where(br_, m_r, m0)))
-            st["diro"] = st["diro"].at[dslot].set(
-                jnp.where(bw_, rr, jnp.where(br_, o_r, o0)))
-            # invalidate other sharers' private lines (the paired
-            # dir_evict calls are no-ops: mask was just set to only-us)
-            inv_act = bw_ & (ninv > 0)
-            r2 = jnp.arange(R)
-            iact = inv_act & (r2 != rr)
-            idx1v = (r2 * S1 + s1)[:, None] * A1 + jnp.arange(A1)[None, :]
-            m1v = (st["l1v"][idx1v] & (st["l1t"][idx1v] == t1)
-                   & iact[:, None])
-            st["l1v"] = st["l1v"].at[idx1v].set(
-                jnp.where(m1v, False, st["l1v"][idx1v]))
-            idx2v = (r2 * S2 + s2)[:, None] * A2 + jnp.arange(A2)[None, :]
-            m2v = (st["l2v"][idx2v] & (st["l2t"][idx2v] == t2)
-                   & iact[:, None])
-            st["l2v"] = st["l2v"].at[idx2v].set(
-                jnp.where(m2v, False, st["l2v"][idx2v]))
-            lat = jnp.where(inv_act, fb.add(lat, B(S.inv_lat)), lat)
-            served = prov
+            with scope("hermes.coherence"):
+                dslot = x["blk_slot"]
+                bit = i64(1) << rr
+                m0 = st["dirm"][dslot]
+                o0 = st["diro"][dslot]
+                bw_ = miss2 & w
+                br_ = miss2 & ~w
+                others = m0 & ~bit
+                ninv = popcount(others)
+                st["dinv"] = st["dinv"] + jnp.where(bw_, ninv, 0)
+                st["dupg"] = st["dupg"] + jnp.where(
+                    bw_ & ((m0 & bit) != 0) & (o0 != rr), 1, 0)
+                prov = br_ & (o0 >= 0) & (o0 != rr)
+                st["dc2c"] = st["dc2c"] + jnp.where(prov, 1, 0)
+                m_r = m0 | bit
+                o_r = jnp.where(prov, i64(-1), o0)
+                o_r = jnp.where((m_r == bit) & ~prov, rr, o_r)
+                st["dirm"] = st["dirm"].at[dslot].set(
+                    jnp.where(bw_, bit, jnp.where(br_, m_r, m0)))
+                st["diro"] = st["diro"].at[dslot].set(
+                    jnp.where(bw_, rr, jnp.where(br_, o_r, o0)))
+                # invalidate other sharers' private lines (the paired
+                # dir_evict calls are no-ops: mask was just set to only-us)
+                inv_act = bw_ & (ninv > 0)
+                r2 = jnp.arange(R)
+                iact = inv_act & (r2 != rr)
+                idx1v = ((r2 * S1 + s1)[:, None] * A1
+                         + jnp.arange(A1)[None, :])
+                m1v = (st["l1v"][idx1v] & (st["l1t"][idx1v] == t1)
+                       & iact[:, None])
+                st["l1v"] = st["l1v"].at[idx1v].set(
+                    jnp.where(m1v, False, st["l1v"][idx1v]))
+                idx2v = ((r2 * S2 + s2)[:, None] * A2
+                         + jnp.arange(A2)[None, :])
+                m2v = (st["l2v"][idx2v] & (st["l2t"][idx2v] == t2)
+                       & iact[:, None])
+                st["l2v"] = st["l2v"].at[idx2v].set(
+                    jnp.where(m2v, False, st["l2v"][idx2v]))
+                lat = jnp.where(inv_act, fb.add(lat, B(S.inv_lat)), lat)
+                served = prov
 
-        cont3 = miss2 & ~served
-        l3hit = jnp.bool_(False)
-        if S.has_l3:
-            if S.mesi:
-                lat = jnp.where(served, fb.add(lat, B(S.c2c_lat)), lat)
-            lat = jnp.where(cont3, fb.add(lat, cfg["hl3"]), lat)
-            s3 = blk & (S3 - 1)
-            hit3, w3, _ = c_probe("l3", s3, blk >> s3b)
-            h3p = cont3 & hit3
-            sl3 = s3 * A3 + w3
-            st["l3h"] = st["l3h"] + jnp.where(h3p, 1, 0)
-            if S.ta3:
-                ta_hit("l3", 0, h3p, st["l3n"][sl3])
-            pu3 = h3p & st["l3p"][sl3]
-            st["l3pu"] = st["l3pu"] + jnp.where(pu3, 1, 0)
-            st["l3p"] = st["l3p"].at[sl3].set(
-                jnp.where(pu3, False, st["l3p"][sl3]))
-            st["l3l"] = st["l3l"].at[sl3].set(
-                jnp.where(h3p, now, st["l3l"][sl3]))
-            st["l3d"] = st["l3d"].at[sl3].set(
-                jnp.where(h3p & w, True, st["l3d"][sl3]))
-            st["l3m"] = st["l3m"] + jnp.where(cont3 & ~hit3, 1, 0)
-            l3hit = h3p
+        # ---- the demand access beyond the private levels ----
+        with scope("hermes.memory"):
+            cont3 = miss2 & ~served
+            l3hit = jnp.bool_(False)
+            if S.has_l3:
+                with scope("hermes.l3"):
+                    if S.mesi:
+                        lat = jnp.where(served, fb.add(lat, B(S.c2c_lat)),
+                                        lat)
+                    lat = jnp.where(cont3, fb.add(lat, cfg["hl3"]), lat)
+                    s3 = blk & (S3 - 1)
+                    hit3, w3, _ = c_probe("l3", s3, blk >> s3b)
+                    h3p = cont3 & hit3
+                    sl3 = s3 * A3 + w3
+                    st["l3h"] = st["l3h"] + jnp.where(h3p, 1, 0)
+                    if S.ta3:
+                        ta_hit("l3", 0, h3p, st["l3n"][sl3])
+                    pu3 = h3p & st["l3p"][sl3]
+                    st["l3pu"] = st["l3pu"] + jnp.where(pu3, 1, 0)
+                    st["l3p"] = st["l3p"].at[sl3].set(
+                        jnp.where(pu3, False, st["l3p"][sl3]))
+                    st["l3l"] = st["l3l"].at[sl3].set(
+                        jnp.where(h3p, now, st["l3l"][sl3]))
+                    st["l3d"] = st["l3d"].at[sl3].set(
+                        jnp.where(h3p & w, True, st["l3d"][sl3]))
+                    st["l3m"] = st["l3m"] + jnp.where(cont3 & ~hit3, 1, 0)
+                    l3hit = h3p
 
-        bm = cont3 & ~l3hit
+            bm = cont3 & ~l3hit
+            # merged: miss path + c2c w/o L3
+            dem = bm if S.has_l3 else (bm | served)
+            _, svc = mem_access(dem, fb.add(now, lat), a, False,
+                                x["pg_slot"])
+            lat = jnp.where(dem, fb.add(lat, svc), lat)
+            fs_pred = (bm | served) if S.has_l3 else bm
+            fill_shared(fs_pred, blk, ten, reu, now, bm & w)
+            fill_private(bm | served | l3hit, rr, blk, ten, reu, now, w)
 
-        # ---- demand memory access (merged: miss path + c2c w/o L3) ----
-        dem = bm if S.has_l3 else (bm | served)
-        _, svc = mem_access(dem, fb.add(now, lat), a, False, x["pg_slot"])
-        lat = jnp.where(dem, fb.add(lat, svc), lat)
-        fs_pred = (bm | served) if S.has_l3 else bm
-        fill_shared(fs_pred, blk, ten, reu, now, bm & w)
-        fill_private(bm | served | l3hit, rr, blk, ten, reu, now, w)
-
-        # ---- retire ----
-        hitdone = h1p | h2p | served | l3hit
-        active = hitdone | bm
-        st["lat"] = jnp.where(active, fb.add(st["lat"], lat), st["lat"])
-        st["nacc"] = st["nacc"] + jnp.where(active, 1, 0)
-        d_ = jnp.where(rr >= NC, fb.div_const(lat, S.accel_mlp),
-                       fb.div_const(lat, S.core_mlp))
-        slow = fb.add(now, jnp.maximum(d_, TWO))
-        fast = hitdone & (lat <= fb.add(cfg["hl1"], TWELVE))
-        newt = jnp.where(fast, fb.add(now, ONE), slow)
-        st["time"] = st["time"].at[rr].set(
-            jnp.where(active, newt, st["time"][rr]))
+        with scope("hermes.retire"):
+            hitdone = h1p | h2p | served | l3hit
+            active = hitdone | bm
+            st["lat"] = jnp.where(active, fb.add(st["lat"], lat), st["lat"])
+            st["nacc"] = st["nacc"] + jnp.where(active, 1, 0)
+            d_ = jnp.where(rr >= NC, fb.div_const(lat, S.accel_mlp),
+                           fb.div_const(lat, S.core_mlp))
+            slow = fb.add(now, jnp.maximum(d_, TWO))
+            fast = hitdone & (lat <= fb.add(cfg["hl1"], TWELVE))
+            newt = jnp.where(fast, fb.add(now, ONE), slow)
+            st["time"] = st["time"].at[rr].set(
+                jnp.where(active, newt, st["time"][rr]))
         return st
 
     return step
@@ -1505,7 +1520,8 @@ def _make_run(static: StaticConfig, batched: bool):
         return lax.scan(body, st, xs)[0]
 
     def export(st):
-        return _export_arrays(static, st)
+        with jax.named_scope("hermes.export"):
+            return _export_arrays(static, st)
 
     if batched:
         # cfg rows, states and trace indices vary per lane; the block
@@ -1520,8 +1536,11 @@ _COMPILED: Dict[tuple, tuple] = {}
 
 #: one record per executed shape bucket, oldest first: the bucket's first
 #: config name, its lanes, its distinct traces, the scan steps, the
-#: simulated accesses (summed over lanes), and the seconds spent
-#: compiling and scanning (device work ended by ``block_until_ready``)
+#: simulated accesses (summed over lanes), the seconds of its
+#: ``hermes.compile`` and ``hermes.scan`` spans (compiling or finding the
+#: program; scanning, ended by ``block_until_ready``), and
+#: ``upload_bytes``, the bytes of the host arrays it put on the device
+#: (block tables, states, lane trace indices, trace chunks)
 SCAN_LOG: List[Dict] = []
 
 
@@ -1600,42 +1619,50 @@ def _execute(static: StaticConfig, preps: List[PreparedTrace],
     n = max(p.n for p in preps)
     C = min(CHUNK, n) if n else 1
     m = -(-n // C) * C
-    # [T, ...] per trace, then the trace-major columns [m, T]
-    consts = {"blk": jnp.asarray(np.stack([p.blk_tab for p in preps]))}
-    cols = [p.padded(m) for p in preps]
-    xs = {k: np.stack([c[k] for c in cols], axis=1) for k in cols[0]}
-    inits = [init_state(static, p) for p in preps]
-    if tids is None:
-        st = {k: jnp.asarray(v) for k, v in inits[0].items()}
-        tid = jnp.int32(0)
-    else:
-        tid = jnp.asarray(tids, jnp.int32)
-        st = {k: jnp.stack([jnp.asarray(i[k]) for i in inits])[tid]
-              for k in inits[0]}
-    chunks = [{k: v[i:i + C] for k, v in xs.items()}
-              for i in range(0, m, C)]
-    key = (static, tids is not None,
-           _signature((consts, cfg, st, chunks[0], tid)))
-    t0 = time.perf_counter()
-    exe = _COMPILED.get(key)
-    if exe is None:
-        scan, export = _make_run(static, tids is not None)
-        exe = (scan.lower(consts, cfg, st, chunks[0], tid).compile(),
-               export.lower(st).compile())
-        _COMPILED[key] = exe
-    t1 = time.perf_counter()
-    scan, export = exe
-    for x in chunks:
-        st = scan(consts, cfg, st, {k: jnp.asarray(v) for k, v in x.items()},
-                  tid)
-    out = jax.block_until_ready(export(st))
-    t2 = time.perf_counter()
+    with span("init_state"):
+        inits = [init_state(static, p) for p in preps]
+    with span("upload"):
+        # [T, ...] per trace, then the trace-major columns [m, T]
+        blk = np.stack([p.blk_tab for p in preps])
+        consts = {"blk": jnp.asarray(blk)}
+        cols = [p.padded(m) for p in preps]
+        xs = {k: np.stack([c[k] for c in cols], axis=1) for k in cols[0]}
+        if tids is None:
+            st = {k: jnp.asarray(v) for k, v in inits[0].items()}
+            tid = jnp.int32(0)
+        else:
+            tid = jnp.asarray(tids, jnp.int32)
+            st = {k: jnp.stack([jnp.asarray(i[k]) for i in inits])[tid]
+                  for k in inits[0]}
+        chunks = [{k: v[i:i + C] for k, v in xs.items()}
+                  for i in range(0, m, C)]
+        key = (static, tids is not None,
+               _signature((consts, cfg, st, chunks[0], tid)))
+    with span("compile") as compiling:
+        exe = _COMPILED.get(key)
+        if exe is None:
+            scan, export = _make_run(static, tids is not None)
+            exe = (scan.lower(consts, cfg, st, chunks[0], tid).compile(),
+                   export.lower(st).compile())
+            _COMPILED[key] = exe
+    with span("scan") as scanning:
+        scan, export = exe
+        for x in chunks:
+            st = scan(consts, cfg, st,
+                      {k: jnp.asarray(v) for k, v in x.items()}, tid)
+        out = jax.block_until_ready(export(st))
+    with span("fetch"):
+        out = tuple(np.asarray(a) for a in out)
     real = [0] if tids is None else tids[:lanes]
-    SCAN_LOG.append({"bucket": label, "lanes": len(real),
-                     "traces": len(preps), "steps": m,
-                     "accesses": sum(preps[t].n for t in real),
-                     "compile_s": t1 - t0, "scan_s": t2 - t1})
-    return tuple(np.asarray(a) for a in out)
+    SCAN_LOG.append({
+        "bucket": label, "lanes": len(real), "traces": len(preps),
+        "steps": m, "accesses": sum(preps[t].n for t in real),
+        "compile_s": compiling.seconds, "scan_s": scanning.seconds,
+        "upload_bytes": (blk.nbytes + tid.nbytes
+                         + sum(np.asarray(v).nbytes
+                               for i in inits for v in i.values())
+                         + sum(v.nbytes for v in xs.values()))})
+    return out
 
 
 def run_single(sp, trace: Dict,
@@ -1663,6 +1690,12 @@ def run_batch(sps: List, traces, caps: Optional[Caps] = None,
     Every trace is laid out at the common extents of all of them and of
     ``caps`` (see :class:`Caps`): pass a campaign's envelope as ``caps``
     and each bucket compiles once for the whole campaign."""
+    with span("run_batch"):
+        return _run_batch(sps, traces, caps)
+
+
+def _run_batch(sps: List, traces, caps: Optional[Caps]
+               ) -> List[Tuple[np.ndarray, np.ndarray]]:
     if isinstance(traces, dict):
         traces = [traces] * len(sps)
     distinct = list({id(t): t for t in traces}.values())
@@ -1715,6 +1748,7 @@ def metrics_from_outputs(sp, trace: Dict, oi: np.ndarray, od: np.ndarray):
     deposit-and-derive path ``JaxHierarchySim.run`` uses."""
     from repro.core.engine_soa import _SimView
     from repro.core.simulator import compute_metrics
-    sim = SoAHierarchySim(sp)
-    _native.deposit_counters(sim, oi, od)
-    return compute_metrics(_SimView(sim, *sim._native_counts), trace)
+    with span("metrics_from_outputs"):
+        sim = SoAHierarchySim(sp)
+        _native.deposit_counters(sim, oi, od)
+        return compute_metrics(_SimView(sim, *sim._native_counts), trace)
