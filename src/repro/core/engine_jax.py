@@ -512,8 +512,11 @@ def init_state(S: StaticConfig, prep: PreparedTrace) -> Dict:
                        "mk2": np.zeros((R, mk), np.int64),
                        "mk3": np.zeros((R, mk), np.int64),
                        "mkc": np.zeros((R, mk), np.int64),
-                       "mkd": np.zeros((R, mk, 9), np.int32),
-                       "mko": np.zeros((R, mk, 9), np.int32)})
+                       # the 9 deltas and counts of a slot lead, so
+                       # the slot axis stays minor and a row is 9
+                       # point reads (no relayout of the whole table)
+                       "mkd": np.zeros((9, R, mk), np.int32),
+                       "mko": np.zeros((9, R, mk), np.int32)})
             # perceptron weights in exact units of 1/8
             for k in ("wpc", "wd1", "wd2"):
                 st[k] = np.zeros((R, S.ml_tsize), np.int64)
@@ -540,7 +543,7 @@ def _h64j(x):
     return x
 
 
-# repro: lint-ok[TH002] known copy-insertion hazard, ROADMAP open item 1 — pre-update gathers on the dict carry; its cost on the chip is not measured yet, accepted until the fused-update rewrite lands
+# repro: lint-ok[TH002] point gathers on the dict carry; the chip compile copies no markov table in the scan body (tests/test_tpu_compile.py), smaller carry copies remain (PERF.md section 5)
 def _make_step(S: StaticConfig):
     i64 = jnp.int64
     R, NC = S.n_req, S.n_cores
@@ -1205,8 +1208,8 @@ def _make_step(S: StaticConfig):
             for col, val in (("mk1", pc), ("mk2", key2), ("mk3", key3)):
                 st[col] = st[col].at[rr, es].set(
                     jnp.where(enew, val, st[col][rr, es]))
-            dr = st["mkd"][rr, es]
-            co = st["mko"][rr, es]
+            dr = st["mkd"][ar9, rr, es]
+            co = st["mko"][ar9, rr, es]
             cnt = st["mkc"][rr, es]
             mfound = (ar9 < cnt) & (dr == d_new.astype(jnp.int32))
             fi_any = jnp.any(mfound)
@@ -1226,8 +1229,8 @@ def _make_step(S: StaticConfig):
             dr3 = jnp.where(shift, dr2[gi], dr2)
             co3 = jnp.where(shift, co2[gi], co2)
             cnt3 = cnt2 - jnp.where(ov, 1, 0)
-            st["mkd"] = st["mkd"].at[rr, es].set(dr3)
-            st["mko"] = st["mko"].at[rr, es].set(co3)
+            st["mkd"] = st["mkd"].at[ar9, rr, es].set(dr3)
+            st["mko"] = st["mko"].at[ar9, rr, es].set(co3)
             st["mkc"] = st["mkc"].at[rr, es].set(
                 jnp.where(b2, cnt3, cnt))
             # candidate lookup (post-update): entry (pc, key3, d_new)
@@ -1235,10 +1238,10 @@ def _make_step(S: StaticConfig):
             flag(b2 & covf, _F_MK)
             ccnt = jnp.where(cf, st["mkc"][rr, cs], 0)
             bc = b2 & cf & (ccnt > 0)
-            cco = st["mko"][rr, cs]
+            cco = st["mko"][ar9, rr, cs]
             bm_ = jnp.where(ar9 < ccnt, cco, jnp.int32(-1))
             bi = jnp.argmax(bm_)
-            best = st["mkd"][rr, cs][bi].astype(i64)
+            best = st["mkd"][bi, rr, cs].astype(i64)
             bb = bc & (best != 0)
             f2 = pmod(key3, S.ml_tsize)
             f3 = pmod(d_new, S.ml_tsize)

@@ -6,6 +6,7 @@ tests to run on the fast engine."""
 
 import dataclasses
 
+import numpy as np
 import pytest
 
 from repro.core import trace as trace_mod
@@ -223,6 +224,85 @@ def test_jax_engine_bit_identical_off_preset():
     for f in dataclasses.fields(want_metrics):
         assert getattr(want_metrics, f.name) == getattr(metrics, f.name), \
             f.name
+
+
+def _markov_eviction_trace():
+    """One core, one PC, every access a new block: each round misses on
+    b, b+1, b+2, then b+2+d, so markov entry (pc, 1, 1) sees the delta d
+    of every round.  Twelve distinct deltas, some repeated, fill its 8
+    places and then evict its least-counted delta and shift the rest."""
+    deltas = [3, 3, 5, 7, 7, 7, 9, 11, 13, 15, 17, 9, 19, 21, 23, 25]
+    blocks = []
+    for k, d in enumerate(deltas):
+        b = 4096 * (k + 1)
+        blocks += [b, b + 1, b + 2, b + 2 + d]
+    n = len(blocks)
+    return {"name": "markov_eviction",
+            "core": np.zeros(n, np.int8), "pc": np.full(n, 7, np.int32),
+            "addr": np.asarray(blocks, np.int64) * 64,
+            "write": np.zeros(n, bool), "tensor": np.zeros(n, np.int16),
+            "reuse": np.zeros(n, np.int8), "meta": {"n_macro_ops": 1}}
+
+
+def _final_state(sp, tr):
+    """The unbatched program's state after ``tr``, scanned by the
+    program ``run_single`` compiled for it (no second compile)."""
+    import jax.numpy as jnp
+    from repro.core import engine_jax as E
+    with E._x64():
+        caps = E.Caps.of(tr)
+        static, cfg = E.split_config(sp, caps.nten)
+        prep = E.prepare_trace(static, tr, caps)
+        consts = {"blk": jnp.asarray(prep.blk_tab[None])}
+        cfgs = E._cfg_scalars(cfg)
+        st = {k: jnp.asarray(v)
+              for k, v in E.init_state(static, prep).items()}
+        xs = {k: v[:, None] for k, v in prep.padded(prep.n).items()}
+        tid = jnp.int32(0)
+        key = (static, False, E._signature((consts, cfgs, st, xs, tid)))
+        scan = E._COMPILED[key][0]
+        st = scan(consts, cfgs, st, xs, tid)
+    return {k: np.asarray(v) for k, v in st.items()}
+
+
+def test_jax_engine_markov_eviction_bit_identical():
+    """A markov entry driven past 8 distinct deltas takes the min-count
+    eviction and shift: the jax engine, batched and unbatched, equals
+    the object reference engine, and its tables show the full entry."""
+    pytest.importorskip("jax")
+    from repro.core import engine_jax, native
+    from repro.core.engine_soa import SoAHierarchySim
+    from repro.core.presets import TENSOR_AWARE
+    from repro.sweep.grid import apply_point
+    tr = _markov_eviction_trace()
+    sps = [TENSOR_AWARE,
+           apply_point(TENSOR_AWARE, {"prefetch.stride_confidence": 1},
+                       name="eager_stride")]
+    want, entry = {}, {}
+    for sp in sps:
+        ref = HierarchySim(sp)
+        metrics = ref.run(tr)
+        want[sp.name] = (_counters_ref(ref), metrics)
+        entry[sp.name] = ref.pf[0].ml.markov[(7, 1, 1)]
+        assert len(entry[sp.name]) == 8
+    batched = engine_jax.run_batch(sps, tr)
+    for sp, (oi, od) in zip(sps, batched):
+        sim = SoAHierarchySim(sp)
+        native.deposit_counters(sim, oi, od)
+        got = (_counters_soa(sim),
+               engine_jax.metrics_from_outputs(sp, tr, oi, od))
+        assert got == want[sp.name], sp.name
+    single = HierarchySim(TENSOR_AWARE, engine="jax")
+    metrics = single.run(tr)
+    assert (_counters_soa(single), metrics) == want[TENSOR_AWARE.name]
+    # the one full entry holds the reference's deltas and counts, in
+    # order, after its evictions and shifts
+    st = _final_state(TENSOR_AWARE, tr)
+    (r,), (slot,) = np.nonzero(st["mkc"] == 8)
+    assert st["mkc"].max() == 8
+    assert list(zip(st["mkd"][:8, r, slot].tolist(),
+                    st["mko"][:8, r, slot].tolist())) == \
+        list(entry[TENSOR_AWARE.name].items())
 
 
 def test_engine_factory_dispatch():
