@@ -4,7 +4,9 @@ against the plain reference, and the result line.
 A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a
 configuration (``bench/configs/<config>.json``, found through the
 ``configs`` entry of that name) under a traffic mix
-(``bench/traffic/<traffic>.json``).  Its per-layer metrics are readers
+(``bench/traffic/<traffic>.json``), whose loop nests are those of
+``traffic/loopnests.py`` or files ``bench/traffic/nests/<name>.py``
+(``traffic/generator.py``).  Its per-layer metrics are readers
 ``bench/metrics/<metric>.py``.  Everything is found by name, so a cell,
 mix, configuration or metric is added as files of its own.
 
@@ -71,6 +73,12 @@ class Cell:
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
         return mod.read
+
+    def traffic(self, seed: int) -> Traffic:
+        """The cell's mix for ``seed``, with its nest files found under
+        the cell's own root."""
+        return Traffic(self.mix, seed,
+                       self.root / "bench" / "traffic" / "nests")
 
 
 def _for_cell(metrics: List[Dict], cell: str) -> List[Dict]:
@@ -143,6 +151,13 @@ class Program:
                                 name=point_label(traffic.points[p]))
                     for p, _ in traffic.lanes]
         self.caps = engine_jax.Caps(**cell.config["caps"])
+        system = cell.config["system"]
+        requesters = system["n_cores"] + bool(system["accel_port"])
+        for wl, tr in traffic.traces.items():
+            if tr["core"].max() >= requesters:
+                raise BenchError(
+                    f"{wl}: core {tr['core'].max()} of the trace is beyond "
+                    f"the configuration's {requesters} requesters")
 
     def check_caps(self, slices: Dict[str, Dict]) -> None:
         for wl, sl in slices.items():
@@ -302,7 +317,7 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool,
     import jax
     clock = CompileClock()
     device = device_info(cell.chips)
-    traffic = Traffic(cell.mix, seed)
+    traffic = cell.traffic(seed)
     program = Program(cell, traffic)
 
     # set-up: one campaign at the cell's shapes compiles the scan and the

@@ -238,10 +238,9 @@ def main(argv=None) -> int:
     os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
     os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
     import harness
-    from traffic.generator import Traffic
     cell = harness.load_cell(args.workload, ROOT)
     device = harness.device_info(cell.chips)     # no TPU: exits
-    program = harness.Program(cell, Traffic(cell.mix, args.seed))
+    program = harness.Program(cell, cell.traffic(args.seed))
     program.campaign(harness.no_annotation)      # compiles the bucket
     out = traced_campaign(program)
     print(json.dumps({"workload": args.workload, "seed": args.seed,

@@ -32,11 +32,10 @@ def main(argv=None) -> int:
     os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
     sys.path[:0] = [str(BENCH), str(ROOT / "src")]
     import harness
-    from traffic.generator import Traffic
     cell = harness.load_cell(args.workload, ROOT)
     device = harness.device_info(cell.chips)
     for seed in args.seeds:
-        traffic = Traffic(cell.mix, seed)
+        traffic = cell.traffic(seed)
         program = harness.Program(cell, traffic)
         t = time.perf_counter()
         camp = program.campaign(harness.no_annotation)

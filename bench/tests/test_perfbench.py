@@ -9,6 +9,7 @@ what it needs itself.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import json
 import shutil
@@ -152,9 +153,12 @@ def _tmp_root(tmp_path: Path) -> Path:
 
 
 def _add_cell(root: Path, cell: str, config: dict, mix: dict,
-              metric_src: str = None) -> None:
+              metric_src: str = None, nests: dict = None) -> None:
     """Add a cell as data: a configuration file, a mix file, optionally a
-    metric reader, and entries appended to BENCHMARK.json."""
+    metric reader and nest files (``{name: source}``), and entries
+    appended to BENCHMARK.json."""
+    for name, src in (nests or {}).items():
+        (root / "bench/traffic/nests" / f"{name}.py").write_text(src)
     (root / "bench/configs" / f"{cell}_cfg.json").write_text(
         json.dumps(config))
     (root / "bench/traffic" / f"{cell}_mix.json").write_text(json.dumps(mix))
@@ -204,6 +208,217 @@ def test_cell_added_as_data_is_found_by_name(tmp_path):
         assert len(first[wl]["addr"]) == 128
         assert first[wl]["meta"]["offset"] == again[wl]["meta"]["offset"]
         np.testing.assert_array_equal(first[wl]["addr"], again[wl]["addr"])
+
+
+# the traffic of the committed cells, pinned: sha256 (first 16 hex
+# digits) of every column of the paper workloads' traces at scale 1.0 as
+# the mix generator seeds them, and of their name and meta; and the first
+# three slice offsets of each committed mix
+_TRACE_SHA = {
+    ("cnn", 7): dict(
+        core="6a545245ad8798f6", pc="dbc303f9323c6e0b",
+        addr="3ae7eca778f62f51", write="0a4529dc77fe8b2d",
+        tensor="b396da5ea88abacb", reuse="dd950a9fc9b7944e",
+        meta="24cc6c061d759e74"),
+    ("rnn", 7): dict(
+        core="ad3117c8925778e9", pc="893f743b43148d40",
+        addr="e31138e356a4ccb9", write="c2daa2e94109ce48",
+        tensor="5dd5581eb13c381c", reuse="52bf0402f1b6b7b0",
+        meta="717cb9e8ee1ae03b"),
+    ("transformer", 7): dict(
+        core="a18a7e1441b83a60", pc="9106c148153050ae",
+        addr="74afed72240d00d5", write="429d87bfc30025f9",
+        tensor="7e13c86304589032", reuse="4007d1619145f6d5",
+        meta="f1f3e9d730b92662"),
+    ("cnn", 2**31 + 17): dict(
+        core="c192d649d117ac36", pc="32022b12785204d4",
+        addr="186cd69375d3094e", write="3de55daf573bc3ff",
+        tensor="f1123bd016909436", reuse="d1c24163724ea0c0",
+        meta="24cc6c061d759e74"),
+    ("rnn", 2**31 + 17): dict(
+        core="bb0a4103a308c393", pc="d20878a76a27b595",
+        addr="2c511a6b64905549", write="b2e79163ff930266",
+        tensor="22a890acf43f1139", reuse="471fa34d40670625",
+        meta="717cb9e8ee1ae03b"),
+    ("transformer", 2**31 + 17): dict(
+        core="9a79a462b075e6b7", pc="60fa9d776c191442",
+        addr="1266541db1fa8695", write="e3c7d22990ded61b",
+        tensor="7065cacb8b9db1e2", reuse="d1921c4284461ec2",
+        meta="f1f3e9d730b92662"),
+}
+_FIRST_OFFSETS = {
+    ("stride_l3_grid32.cnn", 7): [
+        {"cnn": 656475}, {"cnn": 434286}, {"cnn": 475336}],
+    ("stride_l3_grid32.cnn", 2**31 + 17): [
+        {"cnn": 7095}, {"cnn": 20228}, {"cnn": 420523}],
+    ("lat_grid32.cnn", 7): [
+        {"cnn": 652726}, {"cnn": 431806}, {"cnn": 472621}],
+    ("lat_grid32.cnn", 2**31 + 17): [
+        {"cnn": 7054}, {"cnn": 20112}, {"cnn": 418122}],
+    ("table.3wl", 7): [
+        {"cnn": 656112, "rnn": 540938, "transformer": 550201},
+        {"cnn": 622997, "rnn": 500436, "transformer": 623788},
+        {"cnn": 578861, "rnn": 194887, "transformer": 44657}],
+    ("table.3wl", 2**31 + 17): [
+        {"cnn": 7091, "rnn": 25195, "transformer": 486756},
+        {"cnn": 400663, "rnn": 109330, "transformer": 674415},
+        {"cnn": 446698, "rnn": 767104, "transformer": 134463}],
+}
+_PINNED = sorted(
+    [("trace", wl, seed, col, sha)
+     for (wl, seed), cols in _TRACE_SHA.items() for col, sha in cols.items()],
+    key=lambda case: case[2]) + [
+    ("offsets", mix, seed, None, offs)
+    for (mix, seed), offs in _FIRST_OFFSETS.items()]
+
+
+@functools.lru_cache(maxsize=1)
+def _paper_traces(seed: int) -> dict:
+    from traffic.generator import Traffic
+    return Traffic({"workloads": ["cnn", "rnn", "transformer"], "slice": 1},
+                   seed).traces
+
+
+@pytest.mark.parametrize(
+    "kind,name,seed,column,want", _PINNED,
+    ids=[f"{k}-{n}-{s}" + (f"-{c}" if c else "") for k, n, s, c, _ in _PINNED])
+def test_committed_traffic_is_pinned(kind, name, seed, column, want):
+    """The committed cells read the same traces, bit for bit, and the
+    same slice offsets for a seed as when their bounds were set."""
+    from traffic.generator import Traffic
+    if kind == "offsets":
+        mix = json.loads((BENCH / "traffic" / f"{name}.json").read_text())
+        traffic = Traffic(mix, seed)
+        assert [{wl: sl["meta"]["offset"] for wl, sl in traffic.draw().items()}
+                for _ in range(3)] == want
+        return
+    tr = _paper_traces(seed)[name]
+    if column == "meta":
+        raw = json.dumps([tr["name"], tr["meta"]["n_macro_ops"],
+                          [list(t) for t in tr["meta"]["tensors"]]]).encode()
+    else:
+        raw = np.ascontiguousarray(tr[column]).tobytes()
+    assert hashlib.sha256(raw).hexdigest()[:16] == want
+
+
+# a loop nest in a file of its own: two tensors, a few hundred accesses;
+# ``fault`` is a statement that spoils the finished trace ``tr``
+_TOY_NEST = """
+import numpy as np
+from traffic.loopnests import (GEMMINI, REUSE_RESIDENT, REUSE_STREAMING,
+                               Builder, finish)
+
+SEED_OFFSET = {offset}
+
+
+def trace(scale, seed, tokens=4):
+    b = Builder("toy_decode", seed)
+    rows = max(8, int(64 * scale))
+    w_id, w_base = b.alloc.tensor(rows * 64, REUSE_RESIDENT)
+    x_id, x_base = b.alloc.tensor(tokens << 12, REUSE_STREAMING)
+    for core in range(2):
+        b.add(core, 10 + core, w_id, REUSE_RESIDENT, False,
+              b.walk(w_base, rows * 64, reps=tokens))
+    b.add(0, 12, w_id, REUSE_RESIDENT, False,
+          b.hot(w_base, rows * 64, 32 * tokens))
+    b.add(GEMMINI, 20, x_id, REUSE_STREAMING, True,
+          b.stream(x_base, 16 * tokens))
+    b.n_macro = tokens
+    tr = finish(b)
+    {fault}
+    return tr
+"""
+
+
+def _toy_nest(offset: int = 3, fault: str = "pass") -> str:
+    return _TOY_NEST.format(offset=offset, fault=fault)
+
+
+def test_nest_file_added_as_data_is_found_by_name(tmp_path):
+    """A cell whose loop nest is a new file under ``traffic/nests/``
+    loads, draws slices and passes the caps guard, with no edit to any
+    file but entries appended to BENCHMARK.json; the mix's ``params``
+    reach the nest, which is seeded with ``--seed`` plus its
+    ``SEED_OFFSET``, while a loop nest of ``loopnests`` keeps its own."""
+    import harness
+    from traffic import loopnests
+    from traffic.generator import trace_function
+    root = _tmp_root(tmp_path)
+    before = _digest(root)
+    config = json.loads(
+        (BENCH / "configs/hermes_tensor_aware.json").read_text())
+    mix = {"workloads": ["toy_decode", "rnn"], "slice": 256,
+           "params": {"toy_decode": {"tokens": 3}},
+           "grid": {"l3.hit_latency": [30, 42]}}
+    _add_cell(root, "toy.cell", config, mix,
+              nests={"toy_decode": _toy_nest()})
+    after = _digest(root)
+    assert {k for k in before if after[k] != before[k]} == {"BENCHMARK.json"}
+    cell = harness.load_cell("toy.cell", root)
+    seed = 2**31 + 23
+    traffic = cell.traffic(seed)
+    toy = traffic.traces["toy_decode"]
+    assert len(toy["core"]) == 2 * 64 * 3 + 32 * 3 + 16 * 3
+    assert len(toy["meta"]["tensors"]) == 2
+    fn, offset = trace_function("toy_decode",
+                                root / "bench" / "traffic" / "nests")
+    assert offset == 3
+    np.testing.assert_array_equal(toy["addr"],
+                                  fn(1.0, seed + 3, tokens=3)["addr"])
+    assert not np.array_equal(toy["addr"], fn(1.0, seed + 4, tokens=3)["addr"])
+    assert len(fn(1.0, seed + 3)["core"]) == 2 * 64 * 4 + 32 * 4 + 16 * 4
+    np.testing.assert_array_equal(traffic.traces["rnn"]["addr"],
+                                  loopnests.rnn_trace(1.0, seed + 1)["addr"])
+    assert traffic.lanes == [(0, "toy_decode"), (0, "rnn"),
+                             (1, "toy_decode"), (1, "rnn")]
+    program = harness.Program(cell, traffic)
+    for _ in range(3):
+        slices = traffic.draw()
+        assert {wl: len(sl["addr"]) for wl, sl in slices.items()} == {
+            "toy_decode": 256, "rnn": 256}
+        program.check_caps(slices)
+
+
+@pytest.mark.parametrize("case,match", [
+    ("unknown_name", r"'toy_decod' is neither in loopnests\.WORKLOADS "
+                     r"\(cnn, rnn, transformer\) nor a nest file"),
+    ("duplicate_offset", r"toy_decode\.py: SEED_OFFSET 3 is also "
+                         r"nests/other\.py's"),
+    ("offset_of_a_loop_nest", r"toy_decode\.py: SEED_OFFSET must be an int "
+                              r"of at least 3, not 2"),
+    ("stray_params", "params for toy_decodx, which the mix's workloads"),
+    ("tensor_out_of_range", r"toy_decode: tensor ids 0\.\.5 outside the "
+                            "table of 2 tensors"),
+    ("reuse_out_of_set", r"toy_decode: reuse class 3 is not one of"),
+    ("wrong_dtype", "toy_decode: column pc must be a 1-d int32 array, "
+                    "not int64"),
+    ("core_beyond_requesters", "toy_decode: core 6 of the trace is beyond "
+                               "the configuration's 5 requesters"),
+])
+def test_malformed_traffic_is_refused(tmp_path, case, match):
+    """A mix or nest file the simulator cannot take is refused at set-up,
+    with a message that names the workload or the file."""
+    import harness
+    nests = {"toy_decode": _toy_nest(**{
+        "offset_of_a_loop_nest": {"offset": 2},
+        "tensor_out_of_range": {"fault": 'tr["tensor"][7] = 5'},
+        "reuse_out_of_set": {"fault": 'tr["reuse"][7] = 3'},
+        "wrong_dtype": {"fault": 'tr["pc"] = tr["pc"].astype(np.int64)'},
+        "core_beyond_requesters": {"fault": 'tr["core"][7] = 6'},
+    }.get(case, {}))}
+    if case == "duplicate_offset":
+        nests["other"] = _toy_nest()
+    mix = {"workloads": ["toy_decod" if case == "unknown_name"
+                         else "toy_decode"], "slice": 64}
+    if case == "stray_params":
+        mix["params"] = {"toy_decodx": {"tokens": 2}}
+    root = _tmp_root(tmp_path)
+    _add_cell(root, "bad.cell",
+              json.loads((BENCH / "configs/hermes_baseline.json").read_text()),
+              mix, nests=nests)
+    cell = harness.load_cell("bad.cell", root)
+    with pytest.raises((ValueError, harness.BenchError), match=match):
+        harness.Program(cell, cell.traffic(5))
 
 
 def test_committed_cells_load():

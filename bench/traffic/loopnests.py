@@ -23,7 +23,10 @@ well-defined: between two touches of an embedding line, all other
 circulating footprints intervene.
 
 A trace is a dict of parallel numpy arrays (core, pc, addr, write, tensor,
-reuse) plus ``meta`` (n_macro_ops, tensor table).
+reuse) plus ``meta`` (n_macro_ops, tensor table).  :class:`Builder` and
+:func:`finish`, with ``LINE``, ``GEMMINI`` and the reuse classes, are
+what a loop nest kept in a file of its own (``nests/<name>.py``, see
+:mod:`generator`) builds its trace with.
 """
 
 from __future__ import annotations
@@ -54,7 +57,10 @@ class _Alloc:
         return tid, base
 
 
-class _Builder:
+class Builder:
+    """The streams of one trace and the tensors they touch: allocate with
+    ``alloc.tensor``, add streams with :meth:`add`, then :func:`finish`."""
+
     def __init__(self, name: str, seed: int):
         self.name = name
         self.rng = np.random.default_rng(seed)
@@ -103,7 +109,8 @@ class _Builder:
         return base + (i + (i // block) * jump) * LINE
 
 
-def _finish(b: _Builder) -> Dict:
+def finish(b: Builder) -> Dict:
+    """The trace of ``b``: its streams in proportional interleave."""
     order_pos = np.concatenate([
         (np.arange(len(s["addrs"])) + 0.5) / len(s["addrs"])
         + b.rng.uniform(0, 1e-6)  # tie-break
@@ -132,7 +139,7 @@ def _finish(b: _Builder) -> Dict:
 # classifier head form the L3-resident working set.
 # --------------------------------------------------------------------------
 def cnn_trace(scale: float = 1.0, seed: int = 0) -> Dict:
-    b = _Builder("cnn_resnet", seed)
+    b = Builder("cnn_resnet", seed)
     al = b.alloc
     n = lambda k: max(64, int(k * scale))
 
@@ -164,7 +171,7 @@ def cnn_trace(scale: float = 1.0, seed: int = 0) -> Dict:
     b.add(GEMMINI, 220, out_id, REUSE_STREAMING, True,
           b.stream(out_base, n(12_000)))
     b.n_macro = n(4_000)
-    return _finish(b)
+    return finish(b)
 
 
 # --------------------------------------------------------------------------
@@ -173,7 +180,7 @@ def cnn_trace(scale: float = 1.0, seed: int = 0) -> Dict:
 # written by core 0 every step → MESI invalidations at the sharers.
 # --------------------------------------------------------------------------
 def rnn_trace(scale: float = 1.0, seed: int = 1) -> Dict:
-    b = _Builder("rnn_lstm", seed)
+    b = Builder("rnn_lstm", seed)
     al = b.alloc
     n = lambda k: max(64, int(k * scale))
 
@@ -203,7 +210,7 @@ def rnn_trace(scale: float = 1.0, seed: int = 1) -> Dict:
     b.add(GEMMINI, 420, y_id, REUSE_STREAMING, True,
           b.stream(y_base, n(25_000)))
     b.n_macro = n(4_400)
-    return _finish(b)
+    return finish(b)
 
 
 # --------------------------------------------------------------------------
@@ -213,7 +220,7 @@ def rnn_trace(scale: float = 1.0, seed: int = 1) -> Dict:
 # gathers irregular (TA-pinnable); activation tiles streaming.
 # --------------------------------------------------------------------------
 def transformer_trace(scale: float = 1.0, seed: int = 2) -> Dict:
-    b = _Builder("transformer_bert", seed)
+    b = Builder("transformer_bert", seed)
     al = b.alloc
     n = lambda k: max(64, int(k * scale))
 
@@ -243,7 +250,7 @@ def transformer_trace(scale: float = 1.0, seed: int = 2) -> Dict:
     b.add(GEMMINI, 610, act_id, REUSE_STREAMING, False,
           b.stream(act_base + (48 << 20), n(22_000)))
     b.n_macro = n(4_800)
-    return _finish(b)
+    return finish(b)
 
 
 WORKLOADS = {
